@@ -124,8 +124,10 @@ def run(quick: bool = True, iters: int = 30, save_as: str = "engines"):
                     if sign_name == "nonneg" and eng.name != "naive" \
                             and eng.layout != "list_major":
                         continue    # sign-indifferent engines: mixed only
-                    if eng.backend == "pallas" and interpret and B != 8:
-                        continue    # interpreter: reference batch only
+                    if eng.backend == "pallas" and (not interpret or B != 8):
+                        # refused by the TPU compiler; the interpreter
+                        # runs the reference batch only
+                        continue
                     run_as = (select_engine(ctx, U_np)
                               if eng.name == "auto" else eng)
                     res, t_min, t_med = _timed(
@@ -159,9 +161,8 @@ def run(quick: bool = True, iters: int = 30, save_as: str = "engines"):
                         "prefix_depth": (
                             ctx.resolved_prefix_depth
                             if run_as.layout == "list_major" else None),
-                        "interpret_mode": (
-                            bool(resolve_interpret(ctx.interpret))
-                            if run_as.backend == "pallas" else False),
+                        # pallas rows run only in the interpreter
+                        "interpret_mode": run_as.backend == "pallas",
                         "M": M, "R": R, "K": K, "batch": B,
                         "sign": sign_name,
                         "sign_bucket": sign_bucket_label(bucket),

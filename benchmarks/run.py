@@ -65,6 +65,9 @@ def main() -> None:
     args = ap.parse_args()
     quick = not args.full
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
+
     from benchmarks import (bta_tpu, engines, fig1_cf, fig2_multilabel,
                             fig3_halted, streaming, table1_toy,
                             table4_scaling)

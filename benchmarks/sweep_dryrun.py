@@ -1,5 +1,9 @@
 """Dry-run sweep driver: one subprocess per cell (bounds compiler RSS),
-resume-safe (skips cells whose JSON already reports status=ok)."""
+resume-safe (skips cells whose JSON already reports status=ok).
+
+A CPU-only tool: each child compiles on virtual host devices and is
+pinned to the CPU backend, so on a machine with a TPU it never claims
+the chip."""
 import json
 import os
 import subprocess
@@ -44,7 +48,8 @@ def main():
                 r = subprocess.run(
                     [sys.executable, "-m", "repro.launch.dryrun", "--arch",
                      arch, "--shape", shape, "--mesh", mesh, "--out", OUT],
-                    env={**os.environ, "PYTHONPATH": "src"},
+                    env={**os.environ, "PYTHONPATH": "src",
+                         "JAX_PLATFORMS": "cpu"},
                     capture_output=True, text=True, timeout=2400)
             except subprocess.TimeoutExpired:
                 print(f"TIMEOUT {arch} x {shape} x {mesh}", flush=True)

@@ -82,7 +82,6 @@ from repro.core.seplr import (
     random_model,
 )
 from repro.core.sharded import (
-    compat_shard_map,
     hierarchical_merge_topk,
     sharded_blocked_topk,
     sharded_naive_topk,
@@ -113,7 +112,7 @@ __all__ = [
     "from_matrix_factorization", "from_linear_multilabel",
     "from_pairwise_kronecker", "kronecker_query", "normalize_query",
     "random_model",
-    "sharded_norm_topk", "compat_shard_map",
+    "sharded_norm_topk",
     # engine layer
     "ScanState", "ScanStrategy", "pruned_block_scan", "merge_topk_sorted",
     "ta_round_strategy", "blocked_lists_strategy", "list_prefix_strategy",

@@ -46,7 +46,7 @@ from repro.core.driver import (ScanState, batched_pruned_scan,
                                merge_block_into_carry_batched,
                                pruned_block_scan)
 from repro.core.index import TopKIndex
-from repro.core.naive import TopKResult
+from repro.core.naive import SCORE_PRECISION, TopKResult
 from repro.core.strategies import (
     batched_list_prefix_strategy,
     blocked_lists_strategy,
@@ -57,24 +57,9 @@ from repro.core.strategies import (
 Array = jnp.ndarray
 
 
-def _pallas_tail_scorer(targets, u):
-    """``ids -> scores`` via the gather-fused Pallas kernel (TPU tails).
-
-    One fused DMA-per-row kernel instead of an XLA gather + matvec; only
-    worth compiling on real TPU backends (``tail_pallas=True`` there), so
-    the interpret-mode CPU path never pays the per-row interpreter cost.
-    """
-    from repro.kernels.topk_mips import gather_scores_pallas
-
-    def score_fn(ids):
-        return gather_scores_pallas(targets, ids, u)
-
-    return score_fn
-
-
 def _two_phase_list_scan(targets, order_desc, t_sorted_desc, u, k,
                          block_size, max_blocks, max_rounds, layout,
-                         ta_rounds, tail_score_fn=None, m_real=None):
+                         ta_rounds, m_real=None):
     """Contiguous prefix phase chained into a gather-side tail phase.
 
     Phase 1 runs :func:`repro.core.strategies.list_prefix_strategy` over
@@ -95,16 +80,14 @@ def _two_phase_list_scan(targets, order_desc, t_sorted_desc, u, k,
         return_state=True)
     tail = blocked_lists_strategy(order_desc, t_sorted_desc, u, block_size,
                                   rank_by_item=layout.rank_by_item,
-                                  ta_rounds=ta_rounds,
-                                  score_fn=tail_score_fn, m_real=m_real)
+                                  ta_rounds=ta_rounds, m_real=m_real)
     return pruned_block_scan(targets, u, tail, k, max_steps=max_blocks,
                              max_rounds=max_rounds, init_state=state)
 
 
 def _batched_two_phase_list_scan(targets, order_desc, t_sorted_desc, U, k,
                                  block_size, max_blocks, max_rounds, layout,
-                                 ta_rounds, sign, dense, tail_pallas=False,
-                                 m_real=None):
+                                 ta_rounds, sign, dense, m_real=None):
     """Batch-native prefix phase chained into a vmapped gather tail.
 
     Phase 1 is :func:`repro.core.driver.batched_pruned_scan` over
@@ -137,8 +120,7 @@ def _batched_two_phase_list_scan(targets, order_desc, t_sorted_desc, U, k,
         tail = blocked_lists_strategy(
             order_desc, t_sorted_desc, u, block_size,
             rank_by_item=layout.rank_by_item, ta_rounds=ta_rounds,
-            score_fn=_pallas_tail_scorer(targets, u) if tail_pallas
-            else None, m_real=m_real)
+            m_real=m_real)
         return pruned_block_scan(targets, u, tail, k, max_steps=max_blocks,
                                  max_rounds=max_rounds, init_state=st)
 
@@ -147,7 +129,7 @@ def _batched_two_phase_list_scan(targets, order_desc, t_sorted_desc, U, k,
 
 @functools.partial(jax.jit,
                    static_argnames=("k", "block_size", "max_blocks", "sign",
-                                    "dense", "tail_pallas"))
+                                    "dense"))
 def blocked_topk_batched_native(
     targets: Array,
     order_desc: Array,
@@ -159,7 +141,6 @@ def blocked_topk_batched_native(
     layout=None,
     sign: int = 0,
     dense: bool = False,
-    tail_pallas: bool = False,
     m_real=None,
 ) -> TopKResult:
     """Batch-native BTA over the list-prefix layout (DESIGN.md §11).
@@ -184,13 +165,12 @@ def blocked_topk_batched_native(
     res = _batched_two_phase_list_scan(
         targets, order_desc, t_sorted_desc, U, k, block_size, max_blocks,
         -1, layout, ta_rounds=False, sign=sign, dense=dense,
-        tail_pallas=tail_pallas, m_real=m_real)
+        m_real=m_real)
     return res._replace(depth=res.depth * block_size)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("k", "block_size", "max_blocks",
-                                    "tail_pallas"))
+                   static_argnames=("k", "block_size", "max_blocks"))
 def blocked_topk(
     targets: Array,
     order_desc: Array,
@@ -201,7 +181,6 @@ def blocked_topk(
     max_blocks: int = -1,
     rank_desc: Optional[Array] = None,
     layout=None,
-    tail_pallas: bool = False,
     m_real=None,
 ) -> TopKResult:
     """Exact top-K via the Block Threshold Algorithm (single query).
@@ -233,10 +212,7 @@ def blocked_topk(
     if layout is not None and layout.prefix_steps(block_size) > 0:
         res = _two_phase_list_scan(targets, order_desc, t_sorted_desc, u,
                                    k, block_size, max_blocks, -1, layout,
-                                   ta_rounds=False,
-                                   tail_score_fn=_pallas_tail_scorer(
-                                       targets, u) if tail_pallas else None,
-                                   m_real=m_real)
+                                   ta_rounds=False, m_real=m_real)
     else:
         strategy = blocked_lists_strategy(order_desc, t_sorted_desc, u,
                                           block_size, rank_desc=rank_desc,
@@ -277,8 +253,7 @@ def blocked_topk_batched(
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("k", "chunk", "max_rounds",
-                                    "tail_pallas"))
+                   static_argnames=("k", "chunk", "max_rounds"))
 def chunked_ta_topk(
     targets: Array,
     order_desc: Array,
@@ -289,7 +264,6 @@ def chunked_ta_topk(
     chunk: int = 32,
     max_rounds: int = -1,
     layout=None,
-    tail_pallas: bool = False,
     m_real=None,
 ) -> TopKResult:
     """Exact TA whose rounds are processed ``chunk`` at a time.
@@ -319,10 +293,7 @@ def chunked_ta_topk(
             and layout.prefix_steps(chunk) > 0):
         return _two_phase_list_scan(targets, order_desc, t_sorted_desc, u,
                                     k, chunk, -1, max_rounds, layout,
-                                    ta_rounds=True,
-                                    tail_score_fn=_pallas_tail_scorer(
-                                        targets, u) if tail_pallas else None,
-                                    m_real=m_real)
+                                    ta_rounds=True, m_real=m_real)
     strategy = blocked_lists_strategy(order_desc, t_sorted_desc, u, chunk,
                                       rank_desc=rank_desc, ta_rounds=True,
                                       m_real=m_real)
@@ -352,7 +323,7 @@ def chunked_ta_topk_batched(
 
 @functools.partial(jax.jit,
                    static_argnames=("k", "chunk", "max_rounds", "sign",
-                                    "dense", "tail_pallas"))
+                                    "dense"))
 def chunked_ta_topk_batched_native(
     targets: Array,
     order_desc: Array,
@@ -364,7 +335,6 @@ def chunked_ta_topk_batched_native(
     layout=None,
     sign: int = 0,
     dense: bool = False,
-    tail_pallas: bool = False,
     m_real=None,
 ) -> TopKResult:
     """Batch-native chunked TA over the list-prefix layout (DESIGN.md §11).
@@ -393,7 +363,7 @@ def chunked_ta_topk_batched_native(
         targets, order_desc, t_sorted_desc, U, k, chunk,
         max_rounds if chunk == 1 else -1,
         max_rounds, layout, ta_rounds=chunk > 1, sign=sign, dense=dense,
-        tail_pallas=tail_pallas, m_real=m_real)
+        m_real=m_real)
 
 
 # ---------------------------------------------------------------------------
@@ -459,7 +429,8 @@ def norm_pruned_topk_batched(
         start = jnp.maximum(0, jnp.minimum(d0, m - block_size))
         tile = jax.lax.dynamic_slice_in_dim(targets_by_norm, start,
                                             block_size)  # [block, R]
-        scores = U @ tile.T                              # [B, block]
+        scores = jnp.matmul(U, tile.T,
+                            precision=SCORE_PRECISION)   # [B, block]
         rows = start + offs
         # tail block slides back (mask re-reads); pad rows masked too
         valid = jnp.logical_and(rows >= d0, rows < m)

@@ -43,7 +43,7 @@ from typing import Callable, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.naive import TopKResult
+from repro.core.naive import SCORE_PRECISION, TopKResult
 
 Array = jnp.ndarray
 
@@ -84,29 +84,30 @@ def merge_topk_sorted(a_vals: Array, a_ids: Array,
     blocks are reduced block-locally first (:func:`_block_topk`), so the
     merge cost no longer scales with the block width.
     """
-    ka = a_vals.shape[0]
     if jax.default_backend() == "cpu":
         cand_vals = jnp.concatenate([a_vals, b_vals])
         cand_ids = jnp.concatenate([a_ids, b_ids])
         top, pos = jax.lax.top_k(cand_vals, k)
         return top, cand_ids[pos]
-    out_pos = jnp.arange(ka, dtype=jnp.int32)
-    ra = out_pos + jnp.sum(b_vals[None, :] > a_vals[:, None], axis=1,
-                           dtype=jnp.int32)
+    ra = (jnp.arange(a_vals.shape[0], dtype=jnp.int32)
+          + jnp.sum(b_vals[None, :] > a_vals[:, None], axis=1,
+                    dtype=jnp.int32))
     rb = (jnp.arange(b_vals.shape[0], dtype=jnp.int32)
           + jnp.sum(a_vals[:, None] >= b_vals[None, :], axis=0,
                     dtype=jnp.int32))
     # one-hot placement via where (never multiply: values can be -inf, and
     # -inf * 0 would poison the sum with NaN). Merged ranks are distinct
-    # and cover [0, ka+kb), so every output slot < k is filled exactly once.
-    oh_a = ra[:, None] == out_pos[None, :]          # [ka, k] one-hot place
-    oh_b = rb[:, None] == out_pos[None, :]
+    # and cover [0, ka+kb), so every output slot < k <= ka+kb is filled
+    # exactly once.
+    slots = jnp.arange(k, dtype=jnp.int32)
+    oh_a = ra[:, None] == slots[None, :]            # [ka, k] one-hot place
+    oh_b = rb[:, None] == slots[None, :]            # [kb, k]
     zero = jnp.zeros((), a_vals.dtype)
     out_vals = (jnp.sum(jnp.where(oh_a, a_vals[:, None], zero), axis=0)
                 + jnp.sum(jnp.where(oh_b, b_vals[:, None], zero), axis=0))
     out_ids = (jnp.sum(jnp.where(oh_a, a_ids[:, None], 0), axis=0)
                + jnp.sum(jnp.where(oh_b, b_ids[:, None], 0), axis=0))
-    return out_vals[:k], out_ids[:k]
+    return out_vals, out_ids
 
 
 def _block_topk(masked_scores: Array, ids: Array, k: int):
@@ -302,7 +303,8 @@ def pruned_block_scan(
                               (round_cap_eff + chunk - 1) // chunk)
     if strategy.num_steps_dynamic is not None:
         cap_eff = jnp.minimum(cap_eff, strategy.num_steps_dynamic)
-    score = strategy.score or (lambda step, ids, active: targets[ids] @ u)
+    score = strategy.score or (lambda step, ids, active: jnp.matmul(
+        targets[ids], u, precision=SCORE_PRECISION))
     use_visited = strategy.track_visited and strategy.fresh_mask is None
 
     def cond(s: ScanState):

@@ -41,8 +41,8 @@ in two kinds, distinguished by which :class:`Engine` fields they set:
   pre-screening and owns the kernel grid), so the executable lives in a
   per-context cache keyed ``(engine, k, batch-bucket, snapshot
   version)`` — the PR-4 contract, retained only here. A compaction
-  serving ``pallas`` re-traces it; on-TPU argument-passing for the
-  kernel path is future work (ROADMAP).
+  serving ``pallas`` re-traces it. The TPU compiler refuses the kernel,
+  so on a TPU backend the factory raises instead (ROADMAP A2).
 
 Batch sizes are bucketed to the next power of two by both kinds
 (:func:`batch_bucket`; queries padded by repeating the last row, results
@@ -67,7 +67,8 @@ name              exact  needs_index  backend   layout       algorithm
 ``norm``          yes    yes          jax       norm_major   Cauchy-Schwarz norm-block scan
 ``norm_sharded``  yes    yes          jax       norm_sharded shared-tile norm scan under
                                                              shard_map, cross-shard pmax bounds
-``pallas``        yes    yes          pallas    norm_major   norm-block scan as a TPU kernel
+``pallas``        yes    yes          pallas    norm_major   norm-block scan as a Pallas kernel
+                                                             (interpret mode; refused on TPU)
 ``fagin``         yes    yes          numpy     row_major    Fagin's Algorithm (host oracle)
 ``partial``       yes    yes          numpy     row_major    Partial TA, Alg. 3 (host oracle)
 ``auto``          yes    yes          dispatch  —            picks per batch (see below)
@@ -82,10 +83,11 @@ cover every implemented algorithm; the benchmark sweep skips
 ``auto`` picks per query batch: sparse batches go to ``ta`` (zero-weight
 lists are never walked, so TA's per-round work collapses to nnz(u)); dense
 batches over catalogues whose norm spectrum decays go to the norm scan
-(``pallas`` on TPU, ``norm`` elsewhere); flat-spectrum dense batches go to
-``bta``. The sparsity statistic is computed HOST-side from the incoming
-array — dispatch never enqueues work (or a sync) on the device query
-stream.
+``norm``; flat-spectrum dense batches go to ``bta``. ``pallas`` is never
+an ``auto`` candidate: the TPU compiler refuses its kernel
+(:data:`PALLAS_TPU_REFUSAL`), and elsewhere it runs in interpret mode.
+The sparsity statistic is computed HOST-side from the incoming array —
+dispatch never enqueues work (or a sync) on the device query stream.
 
 Aliases accepted by :func:`get_engine`: ``threshold -> ta``,
 ``blocked -> bta``, ``norm_pruned -> norm``, ``topk_mips -> pallas``.
@@ -117,7 +119,7 @@ from repro.core.layout import (DEFAULT_PREFIX_DEPTH,
                                LIST_LAYOUT_MIN_TARGETS,
                                build_layout, pad_rank_by_item,
                                pad_zero_rows)
-from repro.core.naive import TopKResult
+from repro.core.naive import SCORE_PRECISION, TopKResult
 from repro.core.strategies import sign_bucket, sign_bucket_label
 
 Array = jnp.ndarray
@@ -355,7 +357,6 @@ class EngineContext:
       index: optional prebuilt :class:`TopKIndex` (built lazily otherwise).
       block_size: depth/block granularity handed to blocked engines.
       max_blocks: uniform halting budget (``-1`` = run to exactness).
-      interpret: Pallas execution mode (``None`` = autodetect by backend).
       ta_chunk: rounds gathered per chunked-TA step (`ta` engine).
       prefix_depth: ``list_major`` layout prefix rows per dimension.
         ``None`` (default) is ADAPTIVE — the layout turns on at
@@ -376,7 +377,7 @@ class EngineContext:
 
     def __init__(self, targets, index: Optional[TopKIndex] = None,
                  block_size: int = 256, max_blocks: int = -1,
-                 interpret=None, ta_chunk: int = 32,
+                 ta_chunk: int = 32,
                  prefix_depth: Optional[int] = None, version: int = 0,
                  cost_table: Optional["CostTable"] = None):
         self.targets = jnp.asarray(targets, dtype=jnp.float32)
@@ -387,7 +388,6 @@ class EngineContext:
         self.cost_table = cost_table
         self.block_size = block_size
         self.max_blocks = max_blocks
-        self.interpret = interpret
         self.ta_chunk = ta_chunk
         # list_major prefix depth; None -> DEFAULT_PREFIX_DEPTH, 0 disables
         # the layout path entirely (list engines fall back to gathers)
@@ -722,8 +722,8 @@ class EngineContext:
         instead of the optimistic unseen default. Returns self for
         chaining.
         """
-        names = list(engines) if engines is not None else [
-            e.name for e in list_engines() if e.has_executable]
+        names = list(engines) if engines is not None \
+            else executable_engines()
         r = int(self.targets.shape[1])
         own = self.m_bucket
         if m_buckets is None:
@@ -926,7 +926,7 @@ def _naive_args(ctx: EngineContext, bucket: int):
 def _naive_run(args, U, k, cfg):
     T, m = args["targets"], args["m_real"]
     mb = T.shape[0]
-    scores = U @ T.T
+    scores = jnp.matmul(U, T.T, precision=SCORE_PRECISION)
     # pad rows are zero rows: mask them to -inf so they can never outrank
     # a real (possibly all-negative) score
     scores = jnp.where(jnp.arange(mb, dtype=jnp.int32)[None, :] < m,
@@ -961,12 +961,6 @@ def _list_batch_cfg(ctx: EngineContext, U) -> tuple:
     return sign_bucket(U)
 
 
-def _tail_pallas(ctx: EngineContext) -> bool:
-    # gather-fused Pallas tail scoring only pays on real TPU backends
-    return (jax.default_backend() == "tpu"
-            and ctx.resolved_prefix_depth > 0)
-
-
 def _list_args(ctx: EngineContext, bucket: int):
     """Shared args for the list engines: padded index + padded layout."""
     args = dict(ctx.padded_index_arrays(bucket))
@@ -980,7 +974,7 @@ def _list_args(ctx: EngineContext, bucket: int):
 
 
 def _ta_cfg(ctx: EngineContext) -> tuple:
-    return (ctx.ta_chunk, ctx.max_blocks, _tail_pallas(ctx))
+    return (ctx.ta_chunk, ctx.max_blocks)
 
 
 def _ta_run(args, U, k, cfg):
@@ -990,7 +984,7 @@ def _ta_run(args, U, k, cfg):
     # and a sign-bucketed batch takes the batched-native prefix scan —
     # ONE shared tile enumeration for the whole batch (DESIGN.md §11).
     # TA's round unit IS list depth, so a budget caps rounds directly.
-    (chunk, max_rounds, tail_pallas), bcfg, budget = cfg
+    (chunk, max_rounds), bcfg, budget = cfg
     if budget is not None:
         max_rounds = budget if max_rounds < 0 else min(max_rounds, budget)
     lay = args["layout"]
@@ -1001,8 +995,7 @@ def _ta_run(args, U, k, cfg):
         return chunked_ta_topk_batched_native(
             args["targets"], args["order_desc"], args["t_sorted_desc"],
             U, k, chunk=chunk, max_rounds=max_rounds, layout=lay,
-            sign=sign, dense=dense, tail_pallas=tail_pallas,
-            m_real=args["m_real"])
+            sign=sign, dense=dense, m_real=args["m_real"])
 
     # vmapped fallback; a single-sided layout cannot feed the per-query
     # (both-direction) prefix path, so it degrades to the gather scan
@@ -1012,19 +1005,17 @@ def _ta_run(args, U, k, cfg):
         return chunked_ta_topk(args["targets"], args["order_desc"],
                                args["t_sorted_desc"], args["rank_desc"],
                                u, k, chunk=chunk, max_rounds=max_rounds,
-                               layout=lay_pq,
-                               tail_pallas=tail_pallas,
-                               m_real=args["m_real"])
+                               layout=lay_pq, m_real=args["m_real"])
 
     return jax.vmap(one)(U)
 
 
 def _bta_cfg(ctx: EngineContext) -> tuple:
-    return (ctx.block_size, ctx.max_blocks, _tail_pallas(ctx))
+    return (ctx.block_size, ctx.max_blocks)
 
 
 def _bta_run(args, U, k, cfg):
-    (block_size, max_blocks, tail_pallas), bcfg, budget = cfg
+    (block_size, max_blocks), bcfg, budget = cfg
     if budget is not None:
         # budget is list-depth rows; BTA halts at block granularity
         bb = max(1, -(-budget // block_size))
@@ -1037,8 +1028,7 @@ def _bta_run(args, U, k, cfg):
         return blocked_topk_batched_native(
             args["targets"], args["order_desc"], args["t_sorted_desc"],
             U, k, block_size=block_size, max_blocks=max_blocks,
-            layout=lay, sign=sign, dense=dense, tail_pallas=tail_pallas,
-            m_real=args["m_real"])
+            layout=lay, sign=sign, dense=dense, m_real=args["m_real"])
 
     lay_pq = lay if (lay is not None and lay.two_sided) else None
 
@@ -1046,9 +1036,7 @@ def _bta_run(args, U, k, cfg):
         return blocked_topk(args["targets"], args["order_desc"],
                             args["t_sorted_desc"], u, k, block_size,
                             max_blocks, rank_desc=args["rank_desc"],
-                            layout=lay_pq,
-                            tail_pallas=tail_pallas,
-                            m_real=args["m_real"])
+                            layout=lay_pq, m_real=args["m_real"])
 
     return jax.vmap(one)(U)
 
@@ -1120,13 +1108,29 @@ def _norm_sharded_run(args, U, k, cfg):
                 args["ids_sharded"], U, k, block_size, max_blocks)
 
 
+#: Why ``pallas`` does not run on a TPU backend, in the compiler's words
+#: (``tests/test_tpu_compile.py`` compiles the kernel for a described
+#: v5e and pins both refusals).
+PALLAS_TPU_REFUSAL = (
+    "the TPU compiler refuses the kernel. Its (1, 1, tiles) bounds block "
+    "fails 'The Pallas TPU lowering currently requires that the last two "
+    "dimensions of your block shape are divisible by 8 and 128 "
+    "respectively, or be equal to the respective dimensions of the "
+    "overall array', and its in-kernel merge fails 'Unimplemented "
+    "primitive in Pallas TPU lowering for KernelType.TC: top_k'. Use "
+    "'norm', the same norm-ordered block scan in XLA")
+
+
 def _pallas_batched(ctx: EngineContext, k: int):
+    if jax.default_backend() == "tpu":
+        raise ValueError(
+            f"engine 'pallas' does not run on a TPU backend: "
+            f"{PALLAS_TPU_REFUSAL}")
     cat = ctx.catalog       # built eagerly, outside the trace
-    interpret = ctx.interpret
     block_m = jnp.int32(cat.block_m)
 
     def fn(U):
-        vals, ids, stats = cat.query_batch(U, k, interpret=interpret)
+        vals, ids, stats = cat.query_batch(U, k)
         # stats = (rows scored incl. block padding, blocks visited, loaded)
         # exact kernel: vacuous -inf bound => fully certified result, and
         # the pytree structure matches the argument-passing engines so
@@ -1208,9 +1212,9 @@ def select_engine(ctx: EngineContext, U,
     the list engines' per-query cost collapses at
     ``B >= BATCHED_LIST_MIN_B`` — below that they pay the per-query
     lockstep scan), and the catalogue norm spectrum (a decaying spectrum
-    lets the Cauchy-Schwarz scan certify after a few contiguous blocks —
-    the Pallas kernel's best case; a flat spectrum makes it a full scan,
-    so BTA wins when the batched list path is live).
+    lets the Cauchy-Schwarz scan certify after a few contiguous blocks;
+    a flat spectrum makes it a full scan, so BTA wins when the batched
+    list path is live).
     """
     arr = U if isinstance(U, np.ndarray) else np.asarray(U)
     b = 1 if arr.ndim < 2 else arr.shape[0]
@@ -1229,13 +1233,12 @@ def select_engine(ctx: EngineContext, U,
         # fall through to the contiguous norm scan instead.
         return get_engine("ta")
     if ctx.norm_decay < 0.5 or not batched_lists:
-        return get_engine(
-            "pallas" if jax.default_backend() == "tpu" else "norm")
+        return get_engine("norm")
     return get_engine("bta")
 
 
 def auto_candidates():
-    """Engine names :func:`select_engine` can resolve to on this backend.
+    """Engine names :func:`select_engine` can resolve to.
 
     Warming exactly this set covers every dispatch ``auto`` can make
     (including the small-batch routes that prefer the shared-tile norm
@@ -1252,8 +1255,16 @@ def auto_candidates():
     matmul's raw throughput at a given (bucket, sign) is exactly the
     question the cost table answers with measurements.
     """
-    return ["ta", "bta", "naive",
-            "pallas" if jax.default_backend() == "tpu" else "norm"]
+    return ["ta", "bta", "naive", "norm"]
+
+
+def executable_engines() -> List[str]:
+    """Engines with a compiled batched body that THIS backend runs: every
+    registered one except ``pallas`` on a TPU backend
+    (:data:`PALLAS_TPU_REFUSAL`) — the default warm set."""
+    tpu = jax.default_backend() == "tpu"
+    return [e.name for e in list_engines() if e.has_executable
+            and not (tpu and e.backend == "pallas")]
 
 
 def _auto_dispatch(ctx: EngineContext, U, k: int,
@@ -1406,10 +1417,11 @@ register_engine(Engine(
     name="pallas", make_batched=_pallas_batched, exact=True, needs_index=True,
     supports_batch=True, backend="pallas", layout="norm_major",
     traffic=_norm_traffic,
-    description="norm-ordered block scan as a Pallas TPU kernel with "
-                "two-level DMA-skipping bounds (interpret-mode on CPU; "
-                "closure-compiled — the one engine whose compile key "
-                "still carries the snapshot version)"))
+    description="norm-ordered block scan as a Pallas kernel with "
+                "two-level DMA-skipping bounds (interpret mode off-TPU; "
+                "the TPU compiler refuses it; closure-compiled — the one "
+                "engine whose compile key still carries the snapshot "
+                "version)"))
 register_engine(Engine(
     name="fagin", dispatch=_host_oracle_dispatch(_fagin_one), exact=True,
     needs_index=True, supports_batch=False, backend="numpy",

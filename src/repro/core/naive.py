@@ -16,6 +16,14 @@ import jax.numpy as jnp
 
 Array = jnp.ndarray
 
+#: Precision of every scoring contraction in ``repro.core``. On a TPU an
+#: f32 ``dot`` at default precision multiplies in bf16, while the engines'
+#: pruning bounds (TA thresholds, Cauchy-Schwarz norms) are f32
+#: elementwise sums: bf16 scores would be compared against f32 bounds and
+#: would not match a float64 reference. HIGHEST keeps products at f32
+#: accuracy on every backend.
+SCORE_PRECISION = jax.lax.Precision.HIGHEST
+
 
 class TopKResult(NamedTuple):
     values: Array   # [K] (or [B, K]) scores, descending
@@ -55,7 +63,8 @@ def certified_counts(res: TopKResult) -> Array:
 @functools.partial(jax.jit, static_argnames=("k",))
 def naive_topk(targets: Array, u: Array, k: int) -> TopKResult:
     """Exact top-K by full scoring. ``targets: [M, R]``, ``u: [R] or [B, R]``."""
-    scores = jnp.einsum("...r,mr->...m", u, targets)
+    scores = jnp.einsum("...r,mr->...m", u, targets,
+                        precision=SCORE_PRECISION)
     values, indices = jax.lax.top_k(scores, k)
     m = targets.shape[0]
     batch_shape = scores.shape[:-1]

@@ -83,7 +83,7 @@ from repro.core import faults
 from repro.core.driver import NEG_INF, merge_block_into_carry_batched
 from repro.core.engines import (Engine, EngineContext, batch_bucket,
                                 pad_to_bucket)
-from repro.core.naive import TopKResult
+from repro.core.naive import SCORE_PRECISION, TopKResult
 from repro.core.sharded import shard_fold_topk
 
 Array = jnp.ndarray
@@ -385,11 +385,13 @@ def _segmented_tail(base_vals, tomb, base_gids, U, segs, l1=None, *, k, kb):
     if l1 is not None:
         l1_rows, l1_gids, l1_live = l1
         # one [B, R] x [S, C, R] einsum scores every shard's slab densely
-        l1_scores = jnp.einsum("br,scr->sbc", U, l1_rows)
+        l1_scores = jnp.einsum("br,scr->sbc", U, l1_rows,
+                               precision=SCORE_PRECISION)
         l1_scores = jnp.where(l1_live[:, None, :], l1_scores, NEG_INF)
         v, gi = shard_fold_topk(v, gi, l1_scores, l1_gids, k)
     for rows, gid, live in segs:
-        scores = U @ rows.T                   # [B, D] — one dense matmul
+        scores = jnp.matmul(U, rows.T,        # [B, D] — one dense matmul
+                            precision=SCORE_PRECISION)
         scores = jnp.where(live[None, :], scores, NEG_INF)
         v, gi = merge_block_into_carry_batched(v, gi, scores, gid, k)
     return v, gi, n_dropped

@@ -24,6 +24,8 @@ from typing import Optional
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.naive import SCORE_PRECISION
+
 Array = jnp.ndarray
 
 
@@ -49,11 +51,13 @@ class SepLRModel:
 
     def score_all(self, u: Array) -> Array:
         """Naive scoring of every target: ``[R] -> [M]`` or ``[B,R] -> [B,M]``."""
-        return jnp.einsum("...r,mr->...m", u, self.targets)
+        return jnp.einsum("...r,mr->...m", u, self.targets,
+                          precision=SCORE_PRECISION)
 
     def score(self, u: Array, ids: Array) -> Array:
         """Score a subset of targets. ``u: [R]``, ``ids: [n]`` -> ``[n]``."""
-        return self.targets[ids] @ u
+        return jnp.matmul(self.targets[ids], u,
+                          precision=SCORE_PRECISION)
 
 
 # ---------------------------------------------------------------------------

@@ -29,9 +29,9 @@ top-Ks):
    globally-found top-K certifies their remaining norm blocks
    irrelevant. Backs the ``norm_sharded`` registry engine.
 
-All functions are written with ``shard_map`` (via :func:`compat_shard_map`,
-which bridges the ``jax.shard_map`` / ``jax.experimental.shard_map`` API
-split across jax versions) and are used by the serving layer
+All functions are written with ``jax.shard_map`` (replication checking
+off: every function all-gathers before returning, so outputs are
+replicated by construction) and are used by the serving layer
 (`repro.serving`) and the retrieval_cand dry-run cells.
 """
 
@@ -46,7 +46,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.driver import (_dedup_first_occurrence,
                                merge_block_into_carry_batched)
-from repro.core.naive import TopKResult
+from repro.core.naive import SCORE_PRECISION, TopKResult
 
 Array = jnp.ndarray
 NEG_INF = float("-inf")
@@ -74,23 +74,6 @@ def shard_fold_topk(carry_vals: Array, carry_ids: Array,
         carry_vals, carry_ids = merge_block_into_carry_batched(
             carry_vals, carry_ids, scores[s], gids[s], k)
     return carry_vals, carry_ids
-
-
-def compat_shard_map(f, mesh, in_specs, out_specs):
-    """``shard_map`` across the jax API split.
-
-    Newer jax exposes ``jax.shard_map`` (replication checking flag
-    ``check_vma``); 0.4.x only has ``jax.experimental.shard_map.shard_map``
-    (flag ``check_rep``). Checking is disabled either way: every function
-    here all-gathers before returning, so outputs are replicated by
-    construction.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
 
 
 def _axis_size(axis_names: Sequence[str]) -> Array:
@@ -121,7 +104,7 @@ def sharded_naive_topk(mesh, T_spec: P, axis_names: Sequence[str]):
 
     def fn(T: Array, U: Array, k: int) -> TopKResult:
         @functools.partial(
-            compat_shard_map, mesh=mesh,
+            jax.shard_map, mesh=mesh, check_vma=False,
             in_specs=(T_spec, P()),
             out_specs=(P(), P(), P(), P()),
         )
@@ -129,7 +112,8 @@ def sharded_naive_topk(mesh, T_spec: P, axis_names: Sequence[str]):
             m_local = T_local.shape[0]
             shard = _axis_index(axis_names)
             scores = jnp.einsum("br,mr->bm", U_rep, T_local,
-                                preferred_element_type=jnp.float32)
+                                preferred_element_type=jnp.float32,
+                                precision=SCORE_PRECISION)
             vals, idx = jax.lax.top_k(scores, min(k, m_local))
             gidx = idx + shard * m_local
             # all-gather K candidates per shard over every sharded axis
@@ -164,7 +148,7 @@ def sharded_blocked_topk(mesh, specs, axis_names: Sequence[str]):
 
     def fn(T, order_desc, t_sorted_desc, U, k: int, block_size: int = 512):
         @functools.partial(
-            compat_shard_map, mesh=mesh,
+            jax.shard_map, mesh=mesh, check_vma=False,
             in_specs=(T_spec, order_spec, tsorted_spec, P()),
             out_specs=(P(), P(), P(), P()),
         )
@@ -203,7 +187,10 @@ def sharded_blocked_topk(mesh, specs, axis_names: Sequence[str]):
                     cand = jnp.take_along_axis(order_l, cols_eff, axis=1).reshape(-1)
                     fresh = jnp.logical_and(
                         _dedup_first_occurrence(cand, m_local), ~vis_q[cand])
-                    scores = jnp.where(fresh, T_l[cand] @ u_q, NEG_INF)
+                    scores = jnp.where(
+                        fresh, jnp.matmul(T_l[cand], u_q,
+                                          precision=SCORE_PRECISION),
+                        NEG_INF)
                     mv, pos = jax.lax.top_k(
                         jnp.concatenate([vals_q, scores]), kk)
                     mi = jnp.concatenate([ids_q, cand])[pos]
@@ -262,7 +249,7 @@ def hierarchical_merge_topk(mesh, T_spec: P, inner_axes: Sequence[str],
 
     def fn(T: Array, U: Array, k: int) -> TopKResult:
         @functools.partial(
-            compat_shard_map, mesh=mesh,
+            jax.shard_map, mesh=mesh, check_vma=False,
             in_specs=(T_spec, P()),
             out_specs=(P(), P(), P(), P()),
         )
@@ -270,7 +257,8 @@ def hierarchical_merge_topk(mesh, T_spec: P, inner_axes: Sequence[str],
             m_local = T_local.shape[0]
             shard = _axis_index(all_axes)
             scores = jnp.einsum("br,mr->bm", U_rep, T_local,
-                                preferred_element_type=jnp.float32)
+                                preferred_element_type=jnp.float32,
+                                precision=SCORE_PRECISION)
             vals, idx = jax.lax.top_k(scores, min(k, m_local))
             gidx = idx + shard * m_local
             # level 1: merge within the pod (fast ICI)
@@ -321,7 +309,7 @@ def sharded_norm_topk(mesh, axis_names: Sequence[str]):
     def fn(T_sh: Array, norms_sh: Array, ids_sh: Array, U: Array, k: int,
            block_size: int = 256, max_blocks: int = -1) -> TopKResult:
         @functools.partial(
-            compat_shard_map, mesh=mesh,
+            jax.shard_map, mesh=mesh, check_vma=False,
             in_specs=(P(axis_names, None), P(axis_names), P(axis_names),
                       P()),
             out_specs=(P(), P(), P(), P()),
@@ -368,7 +356,8 @@ def sharded_norm_topk(mesh, axis_names: Sequence[str]):
                 d0 = step * blk
                 start = jnp.maximum(0, jnp.minimum(d0, m_local - blk))
                 tile = jax.lax.dynamic_slice_in_dim(T_l, start, blk)
-                scores = U_rep @ tile.T                       # [B, blk]
+                scores = jnp.matmul(U_rep, tile.T,
+                                    precision=SCORE_PRECISION)  # [B, blk]
                 rows = start + offs
                 # tail block slides back (mask re-reads) + padding rows
                 valid = jnp.logical_and(rows >= d0, ids_l[rows] >= 0)

@@ -23,8 +23,7 @@ query and returns a :class:`repro.core.driver.ScanStrategy` for
   bounded by Cauchy-Schwarz (the layout the Pallas backend consumes).
 
 The list strategies leave ``ScanStrategy.score`` as the default dense
-gather + matvec unless a layout or an explicit ``score_fn`` (e.g. the
-Pallas gather-fused kernel) supplies a cheaper path.
+gather + matvec unless a layout supplies a contiguous path.
 
 **Pad-aware index arithmetic** (DESIGN.md §10): every strategy accepts an
 optional ``m_real`` — a TRACED scalar carrying the real catalogue size
@@ -41,17 +40,22 @@ keeps the static-shape behaviour.
 
 from __future__ import annotations
 
-from typing import Callable, Optional
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.driver import BatchedScanStrategy, ScanStrategy
+from repro.core.naive import SCORE_PRECISION
 
 Array = jnp.ndarray
 
 _INT_MAX = 2147483647
+
+
+def _dot(a: Array, b: Array) -> Array:
+    return jnp.matmul(a, b, precision=SCORE_PRECISION)
 
 
 def sign_bucket(U) -> tuple:
@@ -200,7 +204,6 @@ def blocked_lists_strategy(
     rank_desc: Optional[Array] = None,
     ta_rounds: bool = False,
     rank_by_item: Optional[Array] = None,
-    score_fn: Optional[Callable[[Array], Array]] = None,
     m_real=None,
 ) -> ScanStrategy:
     """BTA enumeration: ``R * block_size`` candidates per step.
@@ -230,8 +233,6 @@ def blocked_lists_strategy(
         key precompute — the right trade when this strategy is only the
         rare post-prefix TAIL of a layout scan (DESIGN.md §7). Takes
         precedence over ``rank_desc``.
-      score_fn: optional ``ids -> scores`` override (e.g. the Pallas
-        gather-fused scorer) replacing the default ``targets[ids] @ u``.
       m_real: optional traced real catalogue size (arrays M-bucket
         padded). Clamps, direction flips, bound lookups, freshness keys,
         and the dynamic step/round caps all use it, so pad entries past
@@ -296,11 +297,6 @@ def blocked_lists_strategy(
                     jnp.logical_and(active_slots, first_key[ids] == sk),
                     d < m)
 
-    score = None
-    if score_fn is not None:
-        def score(step, ids, active_slots):
-            return score_fn(ids)
-
     steps_dyn = None if m_real is None else -(-m_real // block_size)
     if ta_rounds and block_size > 1:
         # block_size == 1 falls through: one round per step IS the plain
@@ -311,14 +307,13 @@ def blocked_lists_strategy(
         return ScanStrategy(candidates=candidates, bound=round_bounds,
                             num_steps=-(-M // block_size),
                             track_visited=False, fresh_mask=fresh_mask,
-                            score=score,
                             rounds_per_step=block_size, num_rounds=M,
                             num_steps_dynamic=steps_dyn,
                             num_rounds_dynamic=m_real)
     return ScanStrategy(candidates=candidates, bound=block_bound,
                         num_steps=-(-M // block_size),
                         track_visited=fresh_mask is None,
-                        fresh_mask=fresh_mask, score=score,
+                        fresh_mask=fresh_mask,
                         num_steps_dynamic=steps_dyn)
 
 
@@ -393,7 +388,8 @@ def list_prefix_strategy(
 
     def score(step, ids, active_slots):
         tile = _dir_slice(layout.head_rows, layout.tail_rows, step)
-        return tile.reshape(R * block_size, -1) @ u
+        return jnp.matmul(tile.reshape(R * block_size, -1), u,
+                          precision=SCORE_PRECISION)
 
     # round-major first-occurrence keys from the pre-materialised rank
     # tiles: ranks[r, j, r'] is candidate (r, j)'s position in list r'
@@ -496,7 +492,7 @@ def batched_list_prefix_strategy(
                                       layout.tail_ranks)
         ids = _slice(ids_a, step).reshape(-1)                  # [C] shared
         tile = _slice(rows_a, step).reshape(C, R)
-        scores = (tile @ U.T).T                                # [B, C]
+        scores = _dot(tile, U.T).T                             # [B, C]
         ranks = _slice(ranks_a, step)                          # [R, Bk, R]
         abs_key = step * block_size * R + slot_key             # [R, Bk]
         if dense:
@@ -521,8 +517,8 @@ def batched_list_prefix_strategy(
                         h_ids[None]).reshape(B, C)             # [B, C]
         h_tile = _slice(layout.head_rows, step).reshape(C, R)
         t_tile = _slice(layout.tail_rows, step).reshape(C, R)
-        sh = (h_tile @ U.T).T                                  # [B, C]
-        st = (t_tile @ U.T).T
+        sh = _dot(h_tile, U.T).T                               # [B, C]
+        st = _dot(t_tile, U.T).T
         neg_rep = jnp.repeat(neg, block_size, axis=1,
                              total_repeat_length=C)
         scores = jnp.where(neg_rep, st, sh)
@@ -558,22 +554,22 @@ def batched_list_prefix_strategy(
     def round_bounds(step):
         # Eq. 3 at every depth of the block, per query: [B, Bk]
         if sign > 0:
-            return U @ _t_head(step)
+            return _dot(U, _t_head(step))
         if sign < 0:
-            return U @ _t_tail(step)
-        return u_pos @ _t_head(step) + u_neg @ _t_tail(step)
+            return _dot(U, _t_tail(step))
+        return _dot(u_pos, _t_head(step)) + _dot(u_neg, _t_tail(step))
 
     def block_bound(step):
         # bound at the block's last depth only — one [R] column per side
         end = step * block_size + block_size - 1
         t_h = jax.lax.dynamic_slice(t_sorted_desc, (0, end), (R, 1))[:, 0]
         if sign > 0:
-            return U @ t_h
+            return _dot(U, t_h)
         t_t = jax.lax.dynamic_slice(t_sorted_desc, (0, m - 1 - end),
                                     (R, 1))[:, 0]
         if sign < 0:
-            return U @ t_t
-        return u_pos @ t_h + u_neg @ t_t
+            return _dot(U, t_t)
+        return _dot(u_pos, t_h) + _dot(u_neg, t_t)
 
     if ta_rounds and block_size > 1:
         return BatchedScanStrategy(block=block, bound=round_bounds,
@@ -647,7 +643,7 @@ def norm_block_strategy(
             start = jnp.maximum(0, jnp.minimum(d0, m - block_size))
             tile = jax.lax.dynamic_slice_in_dim(targets_by_norm, start,
                                                 block_size)
-            return tile @ u
+            return jnp.matmul(tile, u, precision=SCORE_PRECISION)
 
     def bound(step):
         return block_bounds[step]

@@ -13,11 +13,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.embedding_bag import embedding_bag_pallas
+from repro.core.naive import SCORE_PRECISION
 from repro.kernels.fm_interaction import fm_interaction_pallas
-from repro.kernels.topk_mips import (HAS_SCALAR_PREFETCH, NEG_INF,
-                                     topk_mips_pallas,
-                                     topk_mips_pallas_batched,
+from repro.kernels.topk_mips import (NEG_INF,
                                      topk_mips_pallas_batched_prefetch,
                                      topk_mips_pallas_prefetch)
 
@@ -36,12 +34,11 @@ class MIPSCatalog:
     scalar-prefetch skip instructions, so their HBM->VMEM DMA never
     happens. The pre-screen can only drop blocks the runtime test would
     drop anyway (lb0 is a true lower bound on the final K-th best), so
-    results AND statistics match the single-level kernels exactly.
+    results AND statistics match a runtime-only scan exactly.
 
     ``interpret=None`` (the default on both query paths) autodetects the
-    Pallas execution mode: interpreter off-TPU, compiled on TPU. When the
-    installed jax lacks ``PrefetchScalarGridSpec`` both query paths fall
-    back to the single-level kernels.
+    Pallas execution mode: interpreter off-TPU, compiled on TPU (where
+    the compiler refuses the kernels, ROADMAP A2).
 
     Args:
       T: ``[M, R]`` catalogue.
@@ -89,7 +86,9 @@ class MIPSCatalog:
         so a certificate, not an estimate. Returns -inf (prescreen off,
         still exact) when the head holds fewer than k real rows.
         """
-        hs = jnp.where(self._head_valid[None, :], U @ self._head.T, NEG_INF)
+        hs = jnp.where(self._head_valid[None, :],
+                       jnp.matmul(U, self._head.T,
+                                  precision=SCORE_PRECISION), NEG_INF)
         kk = min(k, self.head_rows)
         lb0 = jax.lax.top_k(hs, kk)[0][:, kk - 1]
         if kk < k or self.num_real < k:
@@ -100,11 +99,6 @@ class MIPSCatalog:
         """Exact top-K. Returns (values, catalogue ids, stats [3])."""
         u = jnp.asarray(u, jnp.float32)
         bounds = jnp.linalg.norm(u) * self.block_max_norm
-        if not HAS_SCALAR_PREFETCH:
-            vals, local_idx, stats = topk_mips_pallas(
-                self.T_sorted, bounds, u, k, self.block_m,
-                interpret=interpret, num_real=self.num_real)
-            return vals, self._to_catalogue_ids(local_idx), stats
         lb0 = self._lower_bound0(u[None, :], k)[0]
         steps = jnp.arange(self.n_blocks, dtype=jnp.int32)
         # head tiles stay live: lb0's witnesses must reach the merge
@@ -124,11 +118,6 @@ class MIPSCatalog:
         U = jnp.atleast_2d(jnp.asarray(U, jnp.float32))
         u_norm = jnp.linalg.norm(U, axis=1)
         bounds = u_norm[:, None] * self.block_max_norm[None, :]
-        if not HAS_SCALAR_PREFETCH:
-            vals, local_idx, stats = topk_mips_pallas_batched(
-                self.T_sorted, bounds, U, k, self.block_m,
-                interpret=interpret, num_real=self.num_real)
-            return vals, self._to_catalogue_ids(local_idx), stats
         lb0 = self._lower_bound0(U, k)
         super_bounds = u_norm[:, None] * self.super_max_norm[None, :]
         live = (super_bounds > lb0[:, None]).at[:, 0].set(True)
@@ -143,17 +132,6 @@ class MIPSCatalog:
             block_m=self.block_m, tiles_per_step=self.superblock,
             interpret=interpret, num_real=self.num_real)
         return vals, self._to_catalogue_ids(local_idx), stats
-
-
-def embedding_bag(table: Array, ids: Array, mode: str = "sum",
-                  block_b: int = 8, interpret: bool = True) -> Array:
-    """Fused EmbeddingBag. table: [V, d]; ids: [B, F] -> [B, d]."""
-    B = ids.shape[0]
-    pad = (-B) % block_b
-    if pad:
-        ids = jnp.pad(ids, ((0, pad), (0, 0)))
-    out = embedding_bag_pallas(table, ids, mode, block_b, interpret)
-    return out[:B]
 
 
 def fm_interaction(emb: Array, block_b: int = 64,

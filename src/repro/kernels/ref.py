@@ -16,19 +16,6 @@ def topk_mips_ref(T_sorted: Array, u: Array, k: int):
     return vals, idx
 
 
-def embedding_bag_ref(table: Array, ids: Array, weights: Array | None = None,
-                      mode: str = "sum"):
-    """ids: [B, F] fixed-size bags -> [B, d]."""
-    rows = jnp.take(table, ids, axis=0)            # [B, F, d]
-    if weights is not None:
-        rows = rows * weights[..., None]
-    if mode == "sum":
-        return rows.sum(axis=1)
-    if mode == "mean":
-        return rows.mean(axis=1)
-    raise ValueError(mode)
-
-
 def fm_interaction_ref(emb: Array):
     """emb: [B, F, d] -> [B] Rendle sum-square second-order term."""
     s = emb.sum(axis=1)

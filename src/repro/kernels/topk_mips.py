@@ -17,10 +17,10 @@ TPU mapping:
   * the bound test is @pl.when on a scalar — a skipped block costs only
     its (prefetched) DMA, no MXU work.
 
-**Two-level bound hierarchy** (the ``*_prefetch`` kernels): the runtime
-``@pl.when`` test above can only skip MXU work — by the time the bound is
-known false, the BlockSpec pipeline has already issued the tile's
-HBM->VMEM DMA. The prefetch kernels add a second, coarser level: the
+**Two-level bound hierarchy**: the runtime ``@pl.when`` test above can
+only skip MXU work — by the time the bound is known false, the BlockSpec
+pipeline has already issued the tile's HBM->VMEM DMA. The kernels add a
+second, coarser level: the
 caller derives an a-priori lower bound lb0 (top-K of the first,
 largest-norm superblock, one cheap XLA matmul) and pre-screens blocks
 whose Cauchy-Schwarz bound is already below lb0. The surviving scan
@@ -30,7 +30,7 @@ tile, so the pipeline sees an unchanged block index and issues NO DMA at
 all. Because the catalogue is norm-sorted, pre-pruned blocks form a
 suffix, and every pre-pruned block would also have been runtime-pruned
 (its bound <= lb0 <= the running lower bound), so ``n_scored`` /
-``blocks_visited`` statistics are identical to the single-level kernels.
+``blocks_visited`` statistics equal a runtime-only scan's.
 
 The batched variant adds the query dimension to the grid —
 ``grid = (B, n_steps)`` with steps innermost, so each query's scan is
@@ -48,12 +48,15 @@ zero padding added by the catalogue wrapper; their scores are masked to
 -inf so a pad row can never displace a real (possibly negative) score
 from the top-K.
 
-Stats layout (all kernels): ``(rows_scored, blocks_visited, blocks_dma)``
-— the third column is what the two-level hierarchy saves; on the
-single-level kernels it simply counts every grid step.
+Stats layout (both kernels): ``(rows_scored, blocks_visited,
+blocks_dma)`` — the third column is what the two-level hierarchy saves.
 
 ``interpret=None`` autodetects: interpret mode off TPU (CPU CI runs the
-kernel bodies in the Pallas interpreter), compiled on TPU.
+kernel bodies in the Pallas interpreter), compiled on TPU. The TPU
+compiler refuses both kernels as written (ROADMAP A2): the bounds and
+output blocks are not (8, 128)-aligned, and Mosaic has no lowering for
+the ``lax.top_k`` in :func:`_merge_block`. The ``pallas`` engine
+therefore refuses a TPU backend instead of compiling them.
 """
 
 from __future__ import annotations
@@ -66,8 +69,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
-
-HAS_SCALAR_PREFETCH = hasattr(pltpu, "PrefetchScalarGridSpec")
 
 
 def resolve_interpret(interpret):
@@ -95,165 +96,6 @@ def _merge_block(scores, block_start, scratch_vals, scratch_idx,
     top, pos = jax.lax.top_k(cand_vals, k)
     scratch_vals[...] = top
     scratch_idx[...] = jnp.take(cand_idx, pos)
-
-
-# ---------------------------------------------------------------------------
-# Single-level kernels (fallback when scalar prefetch is unavailable)
-# ---------------------------------------------------------------------------
-
-
-def _kernel(bound_ref, t_ref, u_ref, vals_ref, idx_ref, stats_ref,
-            scratch_vals, scratch_idx, *, k: int, block_m: int,
-            num_real: int):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        scratch_vals[...] = jnp.full_like(scratch_vals, NEG_INF)
-        scratch_idx[...] = jnp.full_like(scratch_idx, -1)
-        stats_ref[...] = jnp.zeros_like(stats_ref)
-
-    lb = scratch_vals[k - 1]
-    bound = bound_ref[0]
-
-    @pl.when(bound > lb)
-    def _score():
-        tile = t_ref[...]                                  # [block_m, R]
-        u = u_ref[...]                                     # [R, 1]
-        scores = jnp.dot(tile, u,
-                         preferred_element_type=jnp.float32)[:, 0]
-        _merge_block(scores, i * block_m, scratch_vals, scratch_idx,
-                     k=k, block_m=block_m, num_real=num_real)
-        stats_ref[0] += block_m                            # scored
-        stats_ref[1] += 1                                  # blocks visited
-
-    stats_ref[2] += 1            # single-level: every grid step is a DMA
-    vals_ref[...] = scratch_vals[...]
-    idx_ref[...] = scratch_idx[...]
-
-
-def topk_mips_pallas(T_sorted, block_bounds, u, k: int,
-                     block_m: int = 256, interpret=None,
-                     num_real: int = -1):
-    """T_sorted: [M, R] decreasing-norm order (M % block_m == 0);
-    block_bounds: [n_blocks] = ||u|| * max norm per block; u: [R].
-
-    Returns (values [k], local indices [k], stats [3] = (n_scored,
-    blocks_visited, blocks_dma)). ``num_real`` marks the tail of
-    zero-padded rows (default: no padding). Validated in interpret mode on
-    CPU; compiled path targets TPU VMEM tiling via the BlockSpecs below.
-    """
-    M, R = T_sorted.shape
-    assert M % block_m == 0, (M, block_m)
-    n_blocks = M // block_m
-    num_real = M if num_real < 0 else num_real
-    kernel = functools.partial(_kernel, k=k, block_m=block_m,
-                               num_real=num_real)
-    return pl.pallas_call(
-        kernel,
-        grid=(n_blocks,),
-        in_specs=[
-            pl.BlockSpec((1,), lambda i: (i,)),                    # bound
-            pl.BlockSpec((block_m, R), lambda i: (i, 0)),          # T tile
-            pl.BlockSpec((R, 1), lambda i: (0, 0)),                # u
-        ],
-        out_specs=[
-            pl.BlockSpec((k,), lambda i: (0,)),
-            pl.BlockSpec((k,), lambda i: (0,)),
-            pl.BlockSpec((3,), lambda i: (0,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((k,), jnp.float32),
-            jax.ShapeDtypeStruct((k,), jnp.int32),
-            jax.ShapeDtypeStruct((3,), jnp.int32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((k,), jnp.float32),
-            pltpu.VMEM((k,), jnp.int32),
-        ],
-        interpret=resolve_interpret(interpret),
-    )(block_bounds, T_sorted, u[:, None])
-
-
-def _kernel_batched(bound_ref, t_ref, u_ref, vals_ref, idx_ref, stats_ref,
-                    scratch_vals, scratch_idx, *, k: int, block_m: int,
-                    num_real: int):
-    j = pl.program_id(1)  # block index — innermost, sequential per query
-
-    @pl.when(j == 0)
-    def _init():
-        # a new query's scan begins: reset the carried top-K
-        scratch_vals[...] = jnp.full_like(scratch_vals, NEG_INF)
-        scratch_idx[...] = jnp.full_like(scratch_idx, -1)
-        stats_ref[...] = jnp.zeros_like(stats_ref)
-
-    lb = scratch_vals[k - 1]
-    bound = bound_ref[0, 0]
-
-    @pl.when(bound > lb)
-    def _score():
-        tile = t_ref[...]                                  # [block_m, R]
-        u = u_ref[0]                                       # [R, 1]
-        scores = jnp.dot(tile, u,
-                         preferred_element_type=jnp.float32)[:, 0]
-        _merge_block(scores, j * block_m, scratch_vals, scratch_idx,
-                     k=k, block_m=block_m, num_real=num_real)
-        stats_ref[0, 0] += block_m                         # scored
-        stats_ref[0, 1] += 1                               # blocks visited
-
-    stats_ref[0, 2] += 1
-    vals_ref[0, :] = scratch_vals[...]
-    idx_ref[0, :] = scratch_idx[...]
-
-
-def topk_mips_pallas_batched(T_sorted, block_bounds, U, k: int,
-                             block_m: int = 256, interpret=None,
-                             num_real: int = -1):
-    """Query-grid variant: one launch scans the catalogue for a whole batch.
-
-    T_sorted: [M, R] decreasing-norm order (M % block_m == 0);
-    block_bounds: [B, n_blocks] per-query Cauchy-Schwarz block bounds;
-    U: [B, R] queries.
-
-    Returns (values [B, k], local indices [B, k], stats [B, 3]). The grid
-    is (B, n_blocks) with the block dimension innermost, so the VMEM
-    scratch top-K carries across a query's blocks and resets when the grid
-    advances to the next query. The catalogue tile DMA pattern is identical
-    to the single-query kernel; only the tiny u / bound operands change per
-    grid row.
-    """
-    M, R = T_sorted.shape
-    B = U.shape[0]
-    assert M % block_m == 0, (M, block_m)
-    assert block_bounds.shape == (B, M // block_m), block_bounds.shape
-    n_blocks = M // block_m
-    num_real = M if num_real < 0 else num_real
-    kernel = functools.partial(_kernel_batched, k=k, block_m=block_m,
-                               num_real=num_real)
-    return pl.pallas_call(
-        kernel,
-        grid=(B, n_blocks),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda b, j: (b, j)),             # bound
-            pl.BlockSpec((block_m, R), lambda b, j: (j, 0)),       # T tile
-            pl.BlockSpec((1, R, 1), lambda b, j: (b, 0, 0)),       # u
-        ],
-        out_specs=[
-            pl.BlockSpec((1, k), lambda b, j: (b, 0)),
-            pl.BlockSpec((1, k), lambda b, j: (b, 0)),
-            pl.BlockSpec((1, 3), lambda b, j: (b, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, k), jnp.float32),
-            jax.ShapeDtypeStruct((B, k), jnp.int32),
-            jax.ShapeDtypeStruct((B, 3), jnp.int32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((k,), jnp.float32),
-            pltpu.VMEM((k,), jnp.int32),
-        ],
-        interpret=resolve_interpret(interpret),
-    )(block_bounds, T_sorted, U[:, :, None])
 
 
 # ---------------------------------------------------------------------------
@@ -306,7 +148,9 @@ def topk_mips_pallas_prefetch(T_sorted, block_bounds, tile_idx, live, u,
     DMA for them. live: [n_blocks] int32 — 1 where the pre-screen kept the
     step. Both are SCALAR-PREFETCH operands: they are resident before the
     pipeline starts, which is what lets the index map depend on them.
-    Other arguments and returns as :func:`topk_mips_pallas`.
+    ``block_bounds``: [n_blocks] = ||u|| * max norm per block; ``u``: [R].
+    Returns (values [k], local indices [k], stats [3]); ``num_real``
+    marks the tail of zero-padded rows (default: no padding).
     """
     M, R = T_sorted.shape
     assert M % block_m == 0, (M, block_m)
@@ -441,54 +285,3 @@ def topk_mips_pallas_batched_prefetch(T_sorted, tile_bounds, sb_idx, live,
         ],
         interpret=resolve_interpret(interpret),
     )(sb_idx, live, tile_bounds, T_sorted, U[:, :, None])
-
-
-# ---------------------------------------------------------------------------
-# Gather-fused scoring: score scattered rows without materialising the gather
-# ---------------------------------------------------------------------------
-
-
-def _gather_score_kernel(ids_ref, t_row_ref, u_ref, out_ref):
-    # the row DMA'd for this step IS ids[i] (index-map remap below)
-    out_ref[0] = jnp.dot(t_row_ref[0, :], u_ref[:, 0],
-                         preferred_element_type=jnp.float32)
-
-
-def gather_scores_pallas(T, ids, u, interpret=None):
-    """Score ``C`` scattered catalogue rows as one fused kernel.
-
-    ``T: [M, R]``, ``ids: [C] int32`` (need not be distinct, must be in
-    range), ``u: [R]``. Returns ``T[ids] @ u`` — but the gather never
-    materialises ``[C, R]`` in HBM: ``ids`` is a SCALAR-PREFETCH operand
-    and the BlockSpec index map sends grid step ``i`` straight to row
-    ``ids[i]``, so the pipeline DMAs exactly the rows needed, one
-    ``(1, R)`` tile per step, overlapped with the matvec of the previous
-    row. This is the post-prefix TAIL scorer for the list_major layout
-    (DESIGN.md §7): the rare blocks past the prefix are scored without a
-    separate XLA gather kernel and without HBM round-tripping the
-    gathered rows.
-
-    Falls back to the XLA gather+matvec when the installed jax lacks
-    scalar prefetch. Exposed to the strategies through the ``score_fn``
-    hook of :func:`repro.core.strategies.blocked_lists_strategy`.
-    """
-    if not HAS_SCALAR_PREFETCH:
-        return T[ids] @ u
-    C = ids.shape[0]
-    R = T.shape[1]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(C,),
-        in_specs=[
-            pl.BlockSpec((1, R), lambda i, ids_: (ids_[i], 0)),    # row
-            pl.BlockSpec((R, 1), lambda i, ids_: (0, 0)),          # u
-        ],
-        out_specs=[pl.BlockSpec((1,), lambda i, ids_: (i,))],
-    )
-    (out,) = pl.pallas_call(
-        _gather_score_kernel,
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((C,), jnp.float32)],
-        interpret=resolve_interpret(interpret),
-    )(ids, T, u[:, None])
-    return out

@@ -1,12 +1,15 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=" + \
     os.environ.get("REPRO_DRYRUN_DEVICES", "512")
 
 """Multi-pod dry-run: lower + compile every (arch x shape) on the
 production meshes and record memory/cost/collective analysis.
 
-The two lines above MUST stay the first statements in this module — jax
-locks the device count at first initialisation (see the brief). Do not
+The lines above MUST stay the first statements in this module — jax
+locks the backend and the device count at first initialisation. This is
+a CPU-only tool (virtual host devices), pinned to the CPU backend so it
+never claims a TPU on a machine that has one. Do not
 import this module from tests/benchmarks (they want 1 device); run it as
 ``PYTHONPATH=src python -m repro.launch.dryrun --arch gemma-2b --shape train_4k``.
 

@@ -30,10 +30,14 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
+
     import jax.numpy as jnp
 
     from repro.core import random_model
-    from repro.core.engines import auto_candidates, get_engine, list_engines
+    from repro.core.engines import (auto_candidates, executable_engines,
+                                    get_engine)
     from repro.serving.server import TopKServer
 
     rng = np.random.default_rng(args.seed)
@@ -47,11 +51,11 @@ def main():
         (args.num_queries, args.rank)).astype(np.float32) * spectrum)
 
     if args.engine == "all":
-        # skip the host-only numpy oracles: item-at-a-time python loops
-        # at serving sizes are minutes per batch (they stay reachable by
-        # explicit --engine fagin / partial)
-        engines = [e.name for e in list_engines(exact=True)
-                   if e.name != "auto" and not e.host_only]
+        # every compiled engine this backend runs — not the host-only
+        # numpy oracles: item-at-a-time python loops at serving sizes are
+        # minutes per batch (they stay reachable by explicit --engine
+        # fagin / partial)
+        engines = executable_engines()
         # naive first: it is the ground-truth reference the others are
         # asserted against
         engines.sort(key=lambda n: n != "naive")

@@ -25,27 +25,6 @@ Array = jnp.ndarray
 PyTree = Any
 
 
-def current_abstract_mesh():
-    """``jax.sharding.get_abstract_mesh`` across jax versions.
-
-    Public on newer jax. On 0.4.x the internal getter returns the raw
-    context-manager stack (a tuple; ``()`` when no mesh is active), so
-    anything without mesh attributes is normalised to None — callers
-    already treat None like an empty mesh.
-    """
-    getter = getattr(jax.sharding, "get_abstract_mesh", None)
-    if getter is None:
-        from jax._src import mesh as _mesh
-        getter = getattr(_mesh, "get_abstract_mesh", None)
-    if getter is None:
-        return None
-    try:
-        mesh = getter()
-    except Exception:
-        return None
-    return mesh if hasattr(mesh, "axis_names") else None
-
-
 @dataclasses.dataclass(frozen=True)
 class MeshRules:
     """Maps logical axis names to mesh axis names (or None = replicate)."""
@@ -57,8 +36,8 @@ class MeshRules:
 
     def resolve(self, *logical: Optional[str]) -> P:
         """Translate logical names into a PartitionSpec for the ambient mesh."""
-        mesh = current_abstract_mesh()
-        if mesh is None or mesh.empty:
+        mesh = jax.sharding.get_abstract_mesh()
+        if mesh.empty:
             return P()
         names = set(mesh.axis_names)
 
@@ -82,8 +61,8 @@ DEFAULT_RULES = MeshRules()
 
 def shard(x: Array, rules: MeshRules, *logical: Optional[str]) -> Array:
     """with_sharding_constraint against logical axes; no-op without a mesh."""
-    mesh = current_abstract_mesh()
-    if mesh is None or mesh.empty:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
     return jax.lax.with_sharding_constraint(x, rules.resolve(*logical))
 

@@ -19,8 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.models.common import (ACTIVATIONS, MeshRules,
-                                 current_abstract_mesh, dense_init, shard)
+from repro.models.common import ACTIVATIONS, MeshRules, dense_init, shard
 
 Array = jnp.ndarray
 
@@ -109,8 +108,8 @@ def moe_ffn(
 
 
 def ep_available(n_experts: int, rules: MeshRules) -> bool:
-    mesh = current_abstract_mesh()
-    if mesh is None or mesh.empty or rules.tp not in mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or rules.tp not in mesh.axis_names:
         return False
     return n_experts % dict(mesh.shape)[rules.tp] == 0
 
@@ -139,7 +138,7 @@ def moe_ffn_ep(
 
     Capacity is per (dp-row, expert) — GShard-style local capacity.
     """
-    mesh = current_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     tp = rules.tp
     sizes = dict(mesh.shape)
     tp_size = sizes[tp]

@@ -30,7 +30,6 @@ from repro.models.attention import apply_rope, blocked_attention, decode_attenti
 from repro.models.common import (
     ACTIVATIONS,
     MeshRules,
-    current_abstract_mesh,
     dense_init,
     embed_init,
     rms_norm,
@@ -141,8 +140,8 @@ def _div(n: int, mesh_axis: Optional[str]) -> bool:
     """True if dim n is divisible by the ambient mesh axis size."""
     if mesh_axis is None:
         return False
-    mesh = current_abstract_mesh()
-    if mesh is None or mesh.empty or mesh_axis not in mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh_axis not in mesh.axis_names:
         return False
     return n % dict(mesh.shape)[mesh_axis] == 0
 
@@ -216,10 +215,10 @@ def _attention_block(lp: Dict, x: Array, config: TransformerConfig,
         # scores stay sharded exactly like the cache's seq axis
         cache_spec = kv_cache_specs(config, rules, B, k_cache.shape[1])["k"]
         score_spec = P(cache_spec[1], None, None, cache_spec[2])
-        mesh = current_abstract_mesh()
+        mesh = jax.sharding.get_abstract_mesh()
 
         def seq_shard(s):
-            if mesh is None or mesh.empty:
+            if mesh.empty:
                 return s
             return jax.lax.with_sharding_constraint(s, score_spec)
 
@@ -398,10 +397,10 @@ def kv_cache_specs(config: TransformerConfig, rules: MeshRules,
     parallelism (data x model) instead of 16-way, cutting both the
     per-device cache slice and the per-token attention reads 16x.
     """
-    mesh = current_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     dp = None
     sp = None
-    if mesh is not None and not mesh.empty:
+    if not mesh.empty:
         sizes = dict(mesh.shape)
         dp_axes = rules.dp if isinstance(rules.dp, tuple) else (rules.dp,)
         dp_axes = tuple(a for a in dp_axes if a in sizes)
@@ -462,9 +461,9 @@ def topk_logits(hidden: Array, unembed: Array, k: int,
     ``repro.core.sharded``: local matmul + local top-K, all-gather only
     ``K`` candidates per shard. Without a mesh it degrades to naive.
     """
-    mesh = current_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     tp = rules.tp
-    if mesh is None or mesh.empty or tp not in mesh.axis_names \
+    if mesh.empty or tp not in mesh.axis_names \
             or unembed.shape[1] % dict(mesh.shape)[tp] != 0:
         logits = hidden.astype(jnp.float32) @ unembed.astype(jnp.float32)
         return jax.lax.top_k(logits, k)

@@ -1,14 +1,12 @@
 """Process-level test environment knobs (imported before any test module).
 
-XLA:CPU's parallel LLVM codegen (default split count 32) intermittently
-segfaults inside ``backend_compile`` on jaxlib 0.4.3x once a long-lived
-process has accumulated a few hundred compiled executables — the full
-tier-1 suite reliably hit it in the late warmup-heavy tests while every
-file-subset run passed. Serialising codegen removes the crash; on the
-1-core containers this suite targets it costs nothing (the split only
-helps when spare cores can compile modules concurrently), and on
-multi-core CI it adds a little compile time to a suite dominated by
-execution. Appended so job-level ``XLA_FLAGS`` (e.g. the multidevice
+XLA:CPU's parallel LLVM codegen splits each module into up to 32 parts
+compiled concurrently. The suite compiles several hundred small
+executables per worker process, on hosts whose cores the parallel test
+workers already occupy, so splitting buys nothing there and a long-lived
+process has been seen to crash inside ``backend_compile`` with it on.
+One codegen part per module keeps the suite's compile time flat and its
+runs stable. Appended so job-level ``XLA_FLAGS`` (e.g. the multidevice
 job's ``--xla_force_host_platform_device_count=8``) are preserved.
 """
 

@@ -6,6 +6,7 @@ sparse, and negative-weight queries — new engines registered later are
 covered automatically.
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -139,7 +140,7 @@ def test_auto_selects_norm_backend_for_decaying_catalogues():
     T *= (1.0 / np.sqrt(1.0 + np.arange(2000)))[:, None]
     ctx = EngineContext(T)
     U = jnp.asarray(rng.standard_normal((4, 16)).astype(np.float32))
-    assert select_engine(ctx, U).name in ("norm", "pallas")
+    assert select_engine(ctx, U).name == "norm"
 
 
 def test_auto_selects_bta_for_dense_flat_catalogues():
@@ -151,12 +152,12 @@ def test_auto_selects_bta_for_dense_flat_catalogues():
     U = jnp.asarray(rng.standard_normal((8, 16)).astype(np.float32))
     assert select_engine(ctx, U).name == "bta"
     # below the amortisation threshold the shared-tile norm scan wins
-    assert select_engine(ctx, U[:2]).name in ("norm", "pallas")
+    assert select_engine(ctx, U[:2]).name == "norm"
     # with the list layout off there is no batched path at any B: the
     # per-query list loop never beats the contiguous norm scan
     ctx_off = EngineContext(
         rng.standard_normal((1000, 16)).astype(np.float32), prefix_depth=0)
-    assert select_engine(ctx_off, U).name in ("norm", "pallas")
+    assert select_engine(ctx_off, U).name == "norm"
 
 
 def test_auto_sparse_small_batch_avoids_lockstep_list_scan():
@@ -170,7 +171,7 @@ def test_auto_sparse_small_batch_avoids_lockstep_list_scan():
     ctx = EngineContext(rng.standard_normal((500, 24)).astype(np.float32),
                         prefix_depth=64)
     assert select_engine(ctx, jnp.asarray(U)).name == "ta"
-    assert select_engine(ctx, jnp.asarray(U[:2])).name in ("norm", "pallas")
+    assert select_engine(ctx, jnp.asarray(U[:2])).name == "norm"
 
 
 # ---------------------------------------------------------------------------
@@ -396,6 +397,88 @@ def test_merge_topk_sorted_ties_prefer_carry():
     ov, oi = merge_topk_sorted(av, ai, bv, bi, 3)
     np.testing.assert_allclose(np.asarray(ov), [5.0, 5.0, 3.0])
     assert list(np.asarray(oi)) == [10, 20, 11]   # carry id first on ties
+
+
+# ---------------------------------------------------------------------------
+# The off-CPU branch, run on the CPU: ``jax.default_backend`` patched to
+# "tpu" makes the driver trace its merge network (core/driver.py)
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture
+def tpu_backend(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+@pytest.mark.parametrize("ka,kb,k,case", [
+    (6, 6, 6, "random"), (5, 5, 5, "ties"), (6, 6, 4, "neg_inf_pads"),
+    (3, 3, 5, "k_above_both"), (8, 2, 8, "short_block"),
+])
+def test_merge_network_equals_concat_top_k(ka, kb, k, case, monkeypatch):
+    rng = np.random.default_rng(ka * 10 + k)
+    a = rng.standard_normal(ka).astype(np.float32)
+    b = rng.standard_normal(kb).astype(np.float32)
+    if case == "ties":
+        a, b = np.float32([4, 2, 2, 1, 0]), np.float32([4, 2, 1, 1, -1])
+    if case == "neg_inf_pads":
+        a[ka // 2:] = -np.inf
+        b[1:] = -np.inf
+    a, b = np.sort(a)[::-1].copy(), np.sort(b)[::-1].copy()
+    args = (jnp.asarray(a), jnp.arange(ka, dtype=jnp.int32),
+            jnp.asarray(b), jnp.arange(100, 100 + kb, dtype=jnp.int32), k)
+    cv, ci = merge_topk_sorted(*args)                 # concat + top_k
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    nv, ni = merge_topk_sorted(*args)                 # the merge network
+    np.testing.assert_array_equal(np.asarray(nv), np.asarray(cv))
+    np.testing.assert_array_equal(np.asarray(ni), np.asarray(ci))
+
+
+@pytest.mark.parametrize("sign", ["mixed", "nonneg"])
+@pytest.mark.parametrize("m", [2 ** 11 - 1, 2 ** 11 + 1])
+def test_engines_on_merge_network_branch_equal_naive(m, sign, tpu_backend):
+    """norm/bta/ta traced on the off-CPU branch, at HIGHEST scoring
+    precision, against a float64 dense reference: the engines' f32 bounds
+    and their scores agree, so the answers are exact."""
+    from repro.core.engines import trace_totals
+    # shapes no other test traces, so every engine traces on the branch
+    r, k = 11, {"mixed": 7, "nonneg": 6}[sign]
+    rng = np.random.default_rng(m)
+    T = rng.standard_normal((m, r)).astype(np.float32)
+    T *= (1.0 / np.sqrt(1.0 + np.arange(r)))[None, :].astype(np.float32)
+    U = rng.standard_normal((5, r)).astype(np.float32)
+    if sign == "nonneg":
+        U = np.abs(U)
+    ref = np.sort(U.astype(np.float64) @ T.astype(np.float64).T,
+                  axis=1)[:, ::-1][:, :k]
+    ctx = EngineContext(T, block_size=16, prefix_depth=64)
+    for name in ("norm", "bta", "ta"):
+        before = trace_totals().get(name, 0)
+        res = get_engine(name).run(ctx, jnp.asarray(U), k)
+        assert trace_totals().get(name, 0) > before, \
+            f"{name}: a cached CPU-branch trace ran, not the network"
+        np.testing.assert_allclose(np.asarray(res.values), ref,
+                                   rtol=1e-6, atol=1e-6, err_msg=name)
+        ids = np.asarray(res.indices)
+        own = np.take_along_axis(U @ T.T, ids, axis=1)
+        np.testing.assert_allclose(own, np.asarray(res.values),
+                                   rtol=1e-6, atol=1e-6, err_msg=name)
+
+
+def test_pallas_is_refused_and_never_routed_on_tpu(tpu_backend):
+    from repro.core.engines import (PALLAS_TPU_REFUSAL, auto_candidates,
+                                    executable_engines)
+    assert "pallas" not in auto_candidates()
+    assert "pallas" not in executable_engines()
+    assert "norm" in executable_engines()
+    rng = np.random.default_rng(5)
+    T = rng.standard_normal((300, 8)).astype(np.float32)
+    T *= (1.0 / np.sqrt(1.0 + np.arange(300)))[:, None].astype(np.float32)
+    ctx = EngineContext(T)
+    U = jnp.asarray(rng.standard_normal((2, 8)).astype(np.float32))
+    assert select_engine(ctx, U).name == "norm"
+    with pytest.raises(ValueError, match="does not run on a TPU backend"):
+        get_engine("pallas").run(ctx, U, 3)
+    assert "top_k" in PALLAS_TPU_REFUSAL and "8 and 128" in PALLAS_TPU_REFUSAL
 
 
 def test_pallas_engine_counts_are_block_granular():

@@ -5,8 +5,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.ops import MIPSCatalog, embedding_bag, fm_interaction
-from repro.kernels.ref import embedding_bag_ref, fm_interaction_ref
+from repro.kernels.ops import MIPSCatalog, fm_interaction
+from repro.kernels.ref import fm_interaction_ref
 
 
 @pytest.mark.parametrize("m,r,k,block", [
@@ -76,29 +76,6 @@ def test_topk_mips_flat_norms_stay_exact():
 
 
 @pytest.mark.parametrize("dtype", [np.float32, np.float16])
-@pytest.mark.parametrize("b,f,v,d", [(8, 4, 100, 8), (13, 26, 500, 16),
-                                     (32, 39, 200, 10)])
-def test_embedding_bag_sweep(b, f, v, d, dtype):
-    rng = np.random.default_rng(b * f)
-    table = rng.standard_normal((v, d)).astype(dtype)
-    ids = rng.integers(0, v, (b, f)).astype(np.int32)
-    out = embedding_bag(jnp.asarray(table), jnp.asarray(ids))
-    ref = embedding_bag_ref(jnp.asarray(table), jnp.asarray(ids))
-    tol = 1e-5 if dtype == np.float32 else 2e-2
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32), atol=tol, rtol=tol)
-
-
-def test_embedding_bag_mean_mode():
-    rng = np.random.default_rng(1)
-    table = rng.standard_normal((50, 4)).astype(np.float32)
-    ids = rng.integers(0, 50, (6, 5)).astype(np.int32)
-    out = embedding_bag(jnp.asarray(table), jnp.asarray(ids), mode="mean")
-    ref = embedding_bag_ref(jnp.asarray(table), jnp.asarray(ids), mode="mean")
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
-
-
-@pytest.mark.parametrize("dtype", [np.float32, np.float16])
 @pytest.mark.parametrize("b,f,d", [(16, 4, 8), (50, 39, 10), (128, 26, 16),
                                    (7, 2, 3)])
 def test_fm_interaction_sweep(b, f, d, dtype):
@@ -119,36 +96,3 @@ def test_fm_interaction_matches_explicit_pairwise():
         explicit = sum(float(emb[b, i] @ emb[b, j])
                        for i in range(6) for j in range(i + 1, 6))
         assert abs(out[b] - explicit) < 1e-3
-
-
-def test_gather_scores_pallas_matches_xla_gather():
-    """The gather-fused scorer (scalar-prefetch index-map gather) must equal
-    targets[ids] @ u, including repeated ids."""
-    from repro.kernels.topk_mips import gather_scores_pallas
-    rng = np.random.default_rng(21)
-    T = rng.standard_normal((256, 24)).astype(np.float32)
-    u = rng.standard_normal(24).astype(np.float32)
-    ids = np.concatenate([rng.integers(0, 256, 30),
-                          [0, 0, 255, 255]]).astype(np.int32)
-    out = gather_scores_pallas(jnp.asarray(T), jnp.asarray(ids),
-                               jnp.asarray(u))
-    np.testing.assert_allclose(np.asarray(out), T[ids] @ u,
-                               atol=1e-4, rtol=1e-4)
-
-
-def test_gather_scores_pallas_under_jit_and_vmap():
-    """The tail scorer is called inside jitted, vmapped scan bodies — the
-    kernel must survive both transforms."""
-    import jax
-
-    from repro.kernels.topk_mips import gather_scores_pallas
-    rng = np.random.default_rng(22)
-    T = jnp.asarray(rng.standard_normal((64, 8)).astype(np.float32))
-    U = jnp.asarray(rng.standard_normal((3, 8)).astype(np.float32))
-    ids = jnp.asarray(rng.integers(0, 64, (3, 10)).astype(np.int32))
-    fn = jax.jit(jax.vmap(lambda i, u: gather_scores_pallas(T, i, u)))
-    out = fn(ids, U)
-    ref = np.take(np.asarray(T), np.asarray(ids), axis=0) @ \
-        np.asarray(U)[:, :, None]
-    np.testing.assert_allclose(np.asarray(out), ref[..., 0], atol=1e-4,
-                               rtol=1e-4)

@@ -13,14 +13,9 @@ import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-needs_explicit_mesh = pytest.mark.skipif(
-    not (hasattr(jax, "set_mesh") and hasattr(jax.sharding, "AxisType")),
-    reason="needs the explicit-mesh APIs (jax.set_mesh / sharding.AxisType) "
-           "of newer jax; this interpreter's jax predates them")
-
 
 def _run(code: str, timeout=560):
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=8",
                PYTHONPATH=os.path.join(REPO, "src"))
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
@@ -30,7 +25,6 @@ def _run(code: str, timeout=560):
     return r.stdout
 
 
-@needs_explicit_mesh
 def test_moe_ep_matches_dense_dispatch():
     """EP dispatch (Perf-A) must be numerically identical to the pjit
     global dispatch when capacity is ample, including under sharding."""
@@ -56,7 +50,6 @@ def test_moe_ep_matches_dense_dispatch():
     assert "EP_PARITY_OK" in out
 
 
-@needs_explicit_mesh
 def test_moe_ep_capacity_dropping_is_bounded():
     out = _run("""
         import jax, jax.numpy as jnp
@@ -100,7 +93,6 @@ def test_grouped_decode_attention_matches_dense(n_heads, n_kv):
                                atol=2e-5, rtol=1e-4)
 
 
-@needs_explicit_mesh
 def test_kv_cache_specs_folds_idle_data_axis():
     """Perf-B iter 3: batch=1 -> sequence sharded over data AND model."""
     out = _run("""

@@ -1,12 +1,9 @@
 """Multi-device tests — run in a subprocess with 8 fake host devices so the
 main pytest process keeps its single-device view.
 
-CI runs this file on an 8-virtual-device box (``tier1-multidevice`` job,
-``XLA_FLAGS=--xla_force_host_platform_device_count=8``) with a jax that
-has the explicit-mesh APIs, so nothing here silently skips there. The
-``needs_explicit_mesh`` tests skip on older jax; the ``norm_sharded``
-tests run EVERYWHERE — they only need ``Mesh`` + ``shard_map``, which
-``repro.core.sharded.compat_shard_map`` bridges across jax versions.
+The subprocesses are pinned to the CPU backend (``JAX_PLATFORMS=cpu``):
+they need the 8 virtual host devices, and on a machine with a TPU they
+must never claim the chip the parent process may hold.
 """
 
 import json
@@ -15,19 +12,13 @@ import subprocess
 import sys
 import textwrap
 
-import jax
 import pytest
-
-needs_explicit_mesh = pytest.mark.skipif(
-    not (hasattr(jax, "set_mesh") and hasattr(jax.sharding, "AxisType")),
-    reason="needs the explicit-mesh APIs (jax.set_mesh / sharding.AxisType) "
-           "of newer jax; this interpreter's jax predates them")
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _run(code: str, timeout=560):
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=8",
                PYTHONPATH=os.path.join(REPO, "src"))
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
@@ -37,7 +28,6 @@ def _run(code: str, timeout=560):
     return r.stdout
 
 
-@needs_explicit_mesh
 def test_sharded_topk_exact_all_variants():
     out = _run("""
         import numpy as np, jax, jax.numpy as jnp
@@ -83,7 +73,6 @@ def test_sharded_topk_exact_all_variants():
     assert "SHARDED_OK" in out
 
 
-@needs_explicit_mesh
 def test_topk_logits_sharded_vocab():
     out = _run("""
         import numpy as np, jax, jax.numpy as jnp
@@ -103,7 +92,6 @@ def test_topk_logits_sharded_vocab():
     assert "TOPK_LOGITS_OK" in out
 
 
-@needs_explicit_mesh
 def test_compressed_allreduce_pod_axis():
     out = _run("""
         import numpy as np, jax, jax.numpy as jnp
@@ -125,11 +113,10 @@ def test_compressed_allreduce_pod_axis():
 
 
 @pytest.mark.slow
-@needs_explicit_mesh
 def test_dryrun_cells_tiny_mesh():
     """Integration: the dry-run machinery lowers+compiles representative
     cells of all three families on a tiny in-test mesh."""
-    env = dict(os.environ, REPRO_DRYRUN_DEVICES="8",
+    env = dict(os.environ, JAX_PLATFORMS="cpu", REPRO_DRYRUN_DEVICES="8",
                PYTHONPATH=os.path.join(REPO, "src"))
     for arch, shape in [("fm", "retrieval_cand"), ("pna", "molecule"),
                         ("stablelm-3b", "decode_32k")]:
@@ -147,7 +134,7 @@ def test_dryrun_cells_tiny_mesh():
 def test_norm_sharded_identical_topk_on_8_device_mesh():
     """Acceptance: the norm_sharded engine returns the IDENTICAL top-K set
     as the single-host norm engine on an 8-virtual-device CPU mesh,
-    through the engine registry (version-agnostic: compat_shard_map)."""
+    through the engine registry."""
     out = _run("""
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import EngineContext, get_engine

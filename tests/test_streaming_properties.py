@@ -335,7 +335,7 @@ def test_steady_state_folds_are_compile_free():
 
 def test_norm_sharded_engine_on_ladder_is_exact():
     """The title configuration: the norm_sharded engine querying the
-    sharded LSM catalogue (runs on 1 device via compat_shard_map; CI
+    sharded LSM catalogue (runs on 1 device under jax.shard_map; CI
     re-runs this file under 8 forced host devices)."""
     rng = np.random.default_rng(17)
     base = rng.standard_normal((96, R)).astype(np.float32)
