@@ -4,10 +4,11 @@ DESIGN.md §14's overhead budget, measured end to end: the loadtest's
 saturated closed-loop phase (open-loop arrivals at 8x the sync
 baseline's rate, every result oracle-verified) runs against ONE shared
 async server in interleaved A/B phases — observability DISABLED
-(``repro.obs.set_enabled(False)``: every seam early-outs), then
-EVERYTHING on (metrics registry recording, the event journal, and span
-traces at ``sample_rate=1.0`` — a worse-than-production setting;
-production samples), in interleaved repetitions that ALTERNATE which
+(``repro.obs.set_enabled(False)``: every seam early-outs, and the GC
+hook and compile listener are removed), then EVERYTHING on (metrics
+registry recording, the event journal, the stage events and GC hook,
+and span traces at ``sample_rate=1.0`` — a worse-than-production
+setting; the process-wide tracer keeps 1 request in 100), in interleaved repetitions that ALTERNATE which
 mode runs first. Sharing the server, interleaving, and alternating the
 order is what makes this a CONTROLLED comparison: both sides see
 identical compiled executables, warm cost tables and allocator state,
@@ -185,7 +186,7 @@ def main(argv=None):
         if summary["n_prom_samples"] < 10:
             failures.append("Prometheus rendering parsed to "
                             f"{summary['n_prom_samples']} samples")
-        if trace is None or trace.find("device") is None:
+        if trace is None or trace.find("await") is None:
             failures.append("no full span tree captured at "
                             "sample_rate=1.0")
     if args.check_perf and ratio < 0.9:

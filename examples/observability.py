@@ -89,9 +89,9 @@ with AsyncTopKServer(model, max_batch=16, delta_capacity=32,
         print(ev)
 
     # the join: spans carry (version, epoch); so do compaction events
-    dev = trace.find("device")
-    if dev is not None and "version" in dev.attrs:
-        v = dev.attrs["version"]
+    enq = trace.find("enqueue")
+    if enq is not None and "version" in enq.attrs:
+        v = enq.attrs["version"]
         produced = obs.JOURNAL.events("compaction.success", version=v)
         print(f"\nslowest request ran against snapshot version {v}; "
               f"journal records {len(produced)} compaction.success "

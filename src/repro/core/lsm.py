@@ -324,7 +324,7 @@ class ShardedLsmCatalogue(SegmentedCatalogue):
             self._fold_not_before = 0.0
             self._last_fold_backoff_s = 0.0
             # same join keys as compaction.success (version, epoch): the
-            # journal can join a traced request's device span to the
+            # journal can join a traced request's enqueue span to the
             # exact per-shard state it scanned across the fold
             obs.on_compaction(
                 "fold_l1", version=self._snapshot.version,

@@ -8,11 +8,17 @@ Three process-wide defaults, one switch:
 * :data:`TRACER` — per-request span trees with a sampling knob and a
   bounded store;
 * :data:`JOURNAL` — the bounded structured event journal (compactions,
-  faults, degradations, invalidations, epoch bumps, engine traces).
+  faults, degradations, invalidations, epoch bumps, engine traces, XLA
+  compiles).
 
-``set_enabled(False)`` turns all three into no-op branches — the
-baseline the overhead benchmark (``benchmarks/obs_overhead.py``)
-compares against.
+On the profiler's clock besides: :class:`stage` marks one host stage of
+the served path as a ``jax.profiler`` event, and while the layer is on
+a ``gc.callbacks`` hook marks every Python garbage-collection pause
+(``py.gc``) and a JAX monitoring listener names every XLA compile.
+
+``set_enabled(False)`` turns all of it into no-op branches and removes
+both hooks — the baseline the overhead benchmark
+(``benchmarks/obs_overhead.py``) compares against.
 
 The ``on_*`` helpers below are the ONLY thing production code calls:
 each is one function call at the instrumentation seam, early-outs when
@@ -28,6 +34,14 @@ along the same lines the system specialises along.
 """
 
 from __future__ import annotations
+
+import collections
+import gc
+import time
+
+import jax
+from jax._src.dispatch import BACKEND_COMPILE_EVENT
+from jax.profiler import TraceAnnotation
 
 from repro.obs.events import Event, EventJournal
 from repro.obs.metrics import (
@@ -53,6 +67,7 @@ from repro.obs.trace import Span, Trace, Tracer
 
 __all__ = [
     "REGISTRY", "JOURNAL", "TRACER", "set_enabled", "enabled", "reset",
+    "stage",
     "MetricsRegistry", "Counter", "Gauge", "Histogram", "EventJournal",
     "Event", "Tracer", "Trace", "Span", "log2_buckets",
     "validate_snapshot", "parse_prom_text", "build_mutation_stats",
@@ -61,15 +76,19 @@ __all__ = [
     "GAP_BUCKETS", "SIZE_BUCKETS",
 ]
 
-#: process-wide defaults — the engine/catalogue/serving seams record here
+#: process-wide defaults — the engine/catalogue/serving seams record here.
+#: The tracer keeps every 100th request's span tree (DESIGN.md §14); a
+#: caller that wants every request sets ``TRACER.sample_rate = 1.0``
 REGISTRY = MetricsRegistry()
 JOURNAL = EventJournal(capacity=4096)
-TRACER = Tracer(capacity=256, sample_rate=1.0)
+TRACER = Tracer(capacity=256, sample_rate=0.01)
 
 
 def set_enabled(on: bool) -> None:
-    """Master switch for the default registry, tracer and journal."""
+    """Master switch for the default registry, tracer and journal, the
+    stage events, the GC hook and the compile listener."""
     REGISTRY.enabled = TRACER.enabled = JOURNAL.enabled = bool(on)
+    _install_hooks(bool(on))
 
 
 def enabled() -> bool:
@@ -173,6 +192,15 @@ COST_TABLE_US = REGISTRY.gauge(
     "Measured per-query cost EWMA, per (engine, batch bucket, sign) — "
     "the serving router's table, exported live.",
     labels=("engine", "bucket", "sign"))
+GC_PAUSE = REGISTRY.histogram(
+    "repro_gc_pause_seconds",
+    "Python garbage-collection pauses, per collected generation.",
+    labels=("generation",), buckets=log2_buckets(2.0 ** -20, 64.0))
+XLA_COMPILES = REGISTRY.counter(
+    "repro_xla_compiles_total",
+    "XLA backend compiles (persistent-cache loads included), per jitted "
+    "function name.",
+    labels=("fun",))
 
 
 # ---------------------------------------------------------------------------
@@ -305,3 +333,109 @@ def on_cost_observation(engine: str, bucket: int, label: str,
         return
     COST_TABLE_US.set(1e6 * per_query_s, engine=engine,
                       bucket=str(int(bucket)), sign=label)
+
+
+# ---------------------------------------------------------------------------
+# Host stages, GC pauses and compiles on the profiler's clock
+# ---------------------------------------------------------------------------
+
+class stage:
+    """One host stage of the served path, recorded on two clocks.
+
+    ``with obs.stage("topk.enqueue", batch=seq, n=n) as st:`` opens a
+    ``jax.profiler.TraceAnnotation`` of that name on the working thread,
+    so it lands in a profile beside the device's ops on the device
+    trace's clock, and reads the ``time.perf_counter`` boundaries
+    (``st.start``, ``st.end``) that the per-request trace spans and the
+    server's cost model use — one measurement in both records. ``batch``
+    (the server's micro-batch sequence number) and ``n`` (the real batch
+    size) travel as the event's arguments; a sampled request's root span
+    carries the same ``batch``, the join key from a request to its
+    batch's events. With the layer disabled no event is opened; the
+    clock is still read, since the server's cost model needs it.
+    """
+
+    __slots__ = ("name", "args", "start", "end", "_event")
+
+    def __init__(self, name: str, **args):
+        self.name = name
+        self.args = args
+        self._event = None
+
+    def __enter__(self) -> "stage":
+        if REGISTRY.enabled:
+            self._event = TraceAnnotation(self.name, **self.args)
+        self.start = time.perf_counter()
+        return self
+
+    def set(self, **args) -> None:
+        """Add arguments known only inside the stage to its event."""
+        if self._event is not None:
+            self._event.set_metadata(**args)
+
+    def __exit__(self, *exc) -> None:
+        self.end = time.perf_counter()
+        if self._event is not None:
+            self._event.__exit__(*exc)
+            self._event = None
+
+
+#: the open ``py.gc`` event and its start; collections never nest
+_gc_open = None
+#: pauses not yet in :data:`GC_PAUSE`, oldest first
+_gc_backlog: "collections.deque" = collections.deque(maxlen=4096)
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """``gc.callbacks`` hook: a ``py.gc`` profiler event per collection,
+    and its pause in :data:`GC_PAUSE`. A collection can interrupt this
+    very thread inside a read of that histogram, under its lock, so the
+    pause is recorded only while the lock is free, else at a later
+    pause."""
+    global _gc_open
+    gen = info.get("generation")
+    if phase == "start":
+        _gc_open = (TraceAnnotation("py.gc", generation=gen),
+                    time.perf_counter())
+        return
+    if _gc_open is None:
+        return
+    event, t0 = _gc_open
+    _gc_open = None
+    event.__exit__(None, None, None)
+    _gc_backlog.append((str(gen), time.perf_counter() - t0))
+    while _gc_backlog:
+        g, seconds = _gc_backlog[0]
+        if not GC_PAUSE.try_observe(seconds, generation=g):
+            break
+        _gc_backlog.popleft()
+
+
+def _on_duration(event: str, duration_secs: float, **kwargs) -> None:
+    """JAX monitoring listener: names the function of every backend
+    compile."""
+    if event != BACKEND_COMPILE_EVENT:
+        return
+    fun = str(kwargs.get("fun_name", ""))
+    XLA_COMPILES.inc(fun=fun)
+    JOURNAL.emit("xla.compile", fun=fun, seconds=float(duration_secs))
+
+
+_hooked = False
+
+
+def _install_hooks(on: bool) -> None:
+    global _hooked, _gc_open
+    if on == _hooked:
+        return
+    if on:
+        gc.callbacks.append(_on_gc)
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+    else:
+        gc.callbacks.remove(_on_gc)
+        jax.monitoring.unregister_event_duration_listener(_on_duration)
+        _gc_open = None
+    _hooked = on
+
+
+_install_hooks(True)

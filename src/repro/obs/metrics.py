@@ -203,16 +203,30 @@ class Histogram(_Instrument):
     def observe(self, value: float, **labels) -> None:
         if not self._recording():
             return
-        v = float(value)
-        key = self._key(labels)
-        idx = bisect.bisect_left(self.buckets, v)
         with self._lock:
-            s = self._get(key)
-            s.counts[idx] += 1
-            s.count += 1
-            s.sum += v
-            if s.ring is not None:
-                s.ring.append(v)
+            self._observe_locked(float(value), labels)
+
+    def try_observe(self, value: float, **labels) -> bool:
+        """:meth:`observe` if the lock is free; else nothing, and False.
+        For a caller that may interrupt a holder of the lock on its own
+        thread (a garbage-collector callback)."""
+        if not self._recording():
+            return True
+        if not self._lock.acquire(blocking=False):
+            return False
+        try:
+            self._observe_locked(float(value), labels)
+        finally:
+            self._lock.release()
+        return True
+
+    def _observe_locked(self, v: float, labels: Dict[str, object]) -> None:
+        s = self._get(self._key(labels))
+        s.counts[bisect.bisect_left(self.buckets, v)] += 1
+        s.count += 1
+        s.sum += v
+        if s.ring is not None:
+            s.ring.append(v)
 
     def count(self, **labels) -> int:
         with self._lock:

@@ -7,14 +7,23 @@ threads it through every stage, so a sampled request yields
 
 ::
 
-    topk.request 1843us  engine=bta version=3 epoch=17
+    topk.request 1843us  engine=bta version=3 epoch=17 batch=412
       queue_wait 612us
-      coalesce 48us
+      coalesce 48us  batch_size=5
       route 21us  engine=bta cost_entry=bta|8| predicted_us=310
-      dispatch 95us  batch_size=5 bucket=8 sign=nonneg
-      device 988us
+      dispatch 15us
+      enqueue 80us  engine=bta sign=nonneg version=3 epoch=17
+      await 908us
       harvest 41us
       merge 9us
+
+``enqueue`` runs from the executor call to the device futures it
+returns, ``await`` from those futures to the result on the host: the
+host's wait, which holds the previous micro-batch's device time, this
+one's and the read-back — not device time. The coalesce, route,
+enqueue and await boundaries are those of the micro-batch's
+:class:`repro.obs.stage` events, whose ``batch`` argument equals the
+root's ``batch``: the join from a request to its batch in a profile.
 
 The (snapshot version, mutation epoch) attributes are the JOIN KEYS
 into the event journal (``repro.obs.events``): the compaction event
@@ -23,7 +32,8 @@ the value, so "why was this request slow" can be answered against the
 catalogue state it actually saw (DESIGN.md §14).
 
 Overhead model: cheap counters are ALWAYS on (the metrics registry);
-full span trees are SAMPLED (``Tracer.sample_rate``). An unsampled
+full span trees are SAMPLED (``Tracer.sample_rate``; the process-wide
+``repro.obs.TRACER`` keeps every 100th request). An unsampled
 request costs one lock + one comparison at submit and nothing
 afterwards — ``start_trace`` returns ``None`` and every stage guards on
 that. Span timestamps come from ``time.perf_counter()``; stages that

@@ -498,48 +498,53 @@ class AsyncTopKServer:
         ladder, batch assembly, sign-bucketing — then fire the device
         scan WITHOUT waiting on it and hand the futures to the
         harvester. Runs concurrently with the device scan of the
-        previous micro-batch."""
+        previous micro-batch. Each step is an :class:`obs.stage` of the
+        micro-batch's sequence number."""
         srv = self.server
-        t_pop = time.perf_counter()
+        seq = next(srv._batch_seq)
         k, method = batch[0].k, batch[0].method
         budget = batch[0].budget
         req_name = get_engine(method).name
-        # the token is captured BEFORE the scan dispatches: a mutation
-        # landing mid-scan bumps the live token, so whatever this scan
-        # returns is inserted under a token no future lookup can match
-        token = self.catalogue.cache_token()
-        misses: List[_Request] = []
-        for r in batch:
-            obs.on_queue_wait(1e6 * (t_pop - r.t_enqueue))
-            row = (None if budget is not None
-                   else self.cache.lookup((r.u.tobytes(), r.k, token)))
-            if row is not None:
-                self.pipeline_stats.n_cached += 1
-                if r.trace is not None:
-                    r.trace.span("queue_wait", start=r.t_enqueue,
-                                 end=t_pop)
-                    r.trace.span("cache_hit", start=t_pop,
-                                 version=token[0], epoch=token[1])
-                self._finish_request(r, method, row)
-            else:
-                misses.append(r)
-        if not misses:
-            return
-        n = len(misses)
-        obs.on_batch_formed(n)
-        U = np.stack([r.u for r in misses])
-        t_asm = time.perf_counter()
+        with obs.stage("topk.coalesce", batch=seq, n=len(batch)) as co:
+            # the token is captured BEFORE the scan dispatches: a mutation
+            # landing mid-scan bumps the live token, so whatever this scan
+            # returns is inserted under a token no future lookup can match
+            token = self.catalogue.cache_token()
+            misses: List[_Request] = []
+            for r in batch:
+                obs.on_queue_wait(1e6 * (co.start - r.t_enqueue))
+                row = (None if budget is not None
+                       else self.cache.lookup((r.u.tobytes(), r.k, token)))
+                if row is not None:
+                    self.pipeline_stats.n_cached += 1
+                    if r.trace is not None:
+                        r.trace.root.set(batch=seq)
+                        r.trace.span("queue_wait", start=r.t_enqueue,
+                                     end=co.start)
+                        r.trace.span("cache_hit", start=co.start,
+                                     version=token[0], epoch=token[1])
+                    self._finish_request(r, method, row)
+                else:
+                    misses.append(r)
+            if not misses:
+                return
+            n = len(misses)
+            obs.on_batch_formed(n)
+            U = np.stack([r.u for r in misses])
         req_stats = srv.stats.setdefault(req_name, ServeStats())
-        eng = (select_engine(self.ctx, U) if method == "auto"
-               else get_engine(method))
-        # admission at dispatch time (PR-7 ladder, per micro-batch):
-        # judged against the TIGHTEST deadline riding in the batch
-        deadlines = [r.deadline_s for r in misses
-                     if r.deadline_s is not None]
-        remaining = (min(deadlines) - time.perf_counter()
-                     if deadlines else None)
-        run_eng, bud, rung = srv._admit(eng, n, remaining)
-        t_route = time.perf_counter()
+        with obs.stage("topk.route", batch=seq, n=n) as rt:
+            eng = (select_engine(self.ctx, U) if method == "auto"
+                   else get_engine(method))
+            # admission at dispatch time (PR-7 ladder, per micro-batch):
+            # judged against the TIGHTEST deadline riding in the batch
+            deadlines = [r.deadline_s for r in misses
+                         if r.deadline_s is not None]
+            remaining = (min(deadlines) - time.perf_counter()
+                         if deadlines else None)
+            run_eng, bud, rung = srv._admit(eng, n, remaining)
+            label = (sign_bucket_label(run_eng.batch_config(self.ctx, U))
+                     if run_eng is not None
+                     and run_eng.batch_config is not None else "")
         if rung != "full":
             req_stats.bump_degradation(rung)
             obs.on_degradation(req_name, rung)
@@ -550,19 +555,18 @@ class AsyncTopKServer:
             self.pipeline_stats.n_shed += n
             for r in misses:
                 if r.trace is not None:
+                    r.trace.root.set(batch=seq)
                     r.trace.span("queue_wait", start=r.t_enqueue,
-                                 end=t_pop)
-                    r.trace.span("route", start=t_asm, end=t_route,
+                                 end=co.start)
+                    r.trace.span("route", start=rt.start, end=rt.end,
                                  rung=rung)
-            self._fulfill(misses, method, res, cache_token=None)
+            self._fulfill(misses, method, res, None, seq)
             self.pipeline_stats.n_batches += 1
             self.pipeline_stats.batch_size_hist[n] = \
                 self.pipeline_stats.batch_size_hist.get(n, 0) + 1
             return
         if bud is None:
             bud = budget
-        label = (sign_bucket_label(run_eng.batch_config(self.ctx, U))
-                 if run_eng.batch_config is not None else "")
         # span annotations are assembled once per batch, only when at
         # least one rider is traced (sampling keeps this off the common
         # path): the cost-table entry the router consulted plus the
@@ -573,15 +577,15 @@ class AsyncTopKServer:
             key = run_eng.name if bud is None else f"{run_eng.name}@budget"
             pred = srv.cost_table.predict(key, bucket, label)
             tinfo = {
-                "t_pop": t_pop, "t_asm": t_asm, "t_route": t_route,
+                "coalesce": (co.start, co.end), "route": (rt.start, rt.end),
                 "engine": run_eng.name, "rung": rung,
                 "cost_entry": f"{key}|{bucket}|{label}",
                 "predicted_us": (None if pred is None else 1e6 * pred),
                 "sign": label, "batch_size": n,
                 "version": token[0], "epoch": token[1],
             }
-        t0 = time.perf_counter()
-        res, info = self.catalogue.query(run_eng, U, k, budget=bud)
+        with obs.stage("topk.enqueue", batch=seq, n=n) as enq:
+            res, info = self.catalogue.query(run_eng, U, k, budget=bud)
         # NO np.asarray here: the result is a device future; blocking is
         # the harvester's job. This put() back-pressures the dispatcher
         # once `pipeline_depth` micro-batches are unharvested.
@@ -590,8 +594,9 @@ class AsyncTopKServer:
         self.pipeline_stats.n_batches += 1
         self.pipeline_stats.batch_size_hist[n] = \
             self.pipeline_stats.batch_size_hist.get(n, 0) + 1
-        self._harvest.put((misses, method, run_eng, bud, rung, label,
-                           res, info, t0, token, tinfo))
+        with obs.stage("topk.backpressure", batch=seq, n=n):
+            self._harvest.put((misses, method, run_eng, bud, label, res,
+                               info, enq, token, tinfo, seq))
 
     # -- stage 2: the harvester (device sync side) ---------------------------
 
@@ -600,65 +605,39 @@ class AsyncTopKServer:
             item = self._harvest.get()
             if item is None:
                 return
-            (misses, method, run_eng, bud, rung, label,
-             res, info, t0, token, tinfo) = item
+            (misses, method, run_eng, bud, label, res, info, enq, token,
+             tinfo, seq) = item
+            n = len(misses)
             try:
-                res = jax.tree_util.tree_map(np.asarray, res)  # blocks
-                t_harvested = time.perf_counter()
-                dt = t_harvested - t0
-                n = len(misses)
-                if res.upper is None:
-                    res = res._replace(upper=np.full(
-                        (np.asarray(res.values).shape[0],), -np.inf,
-                        np.float32))
-                req_stats = self.stats.setdefault(
-                    get_engine(method).name, ServeStats())
-                if bud is not None:
-                    self.server._note_certificates(
-                        req_stats, run_eng.name, bud, res)
-                key = (run_eng.name if bud is None
-                       else f"{run_eng.name}@budget")
-                per_q = dt / max(n, 1)
-                prev = self.server._cost_ewma.get(key)
-                self.server._cost_ewma[key] = (
-                    per_q if prev is None else 0.8 * prev + 0.2 * per_q)
-                self.cost_table.observe(key, batch_bucket(n), label, per_q)
-                self.server._record(run_eng.name, res, dt, n,
-                                    info.delta_scored, sign_label=label)
-                if tinfo is not None:
-                    t_done = time.perf_counter()
-                    for r in misses:
-                        if r.trace is None:
-                            continue
-                        r.trace.root.set(engine=tinfo["engine"],
-                                         version=tinfo["version"],
-                                         epoch=tinfo["epoch"])
-                        r.trace.span("queue_wait", start=r.t_enqueue,
-                                     end=tinfo["t_pop"])
-                        r.trace.span("coalesce", start=tinfo["t_pop"],
-                                     end=tinfo["t_asm"],
-                                     batch_size=tinfo["batch_size"])
-                        r.trace.span("route", start=tinfo["t_asm"],
-                                     end=tinfo["t_route"],
-                                     engine=tinfo["engine"],
-                                     rung=tinfo["rung"],
-                                     cost_entry=tinfo["cost_entry"],
-                                     predicted_us=tinfo["predicted_us"])
-                        r.trace.span("dispatch", start=tinfo["t_route"],
-                                     end=t0)
-                        r.trace.span("device", start=t0,
-                                     end=t_harvested,
-                                     engine=tinfo["engine"],
-                                     sign=tinfo["sign"],
-                                     version=tinfo["version"],
-                                     epoch=tinfo["epoch"])
-                        r.trace.span("harvest", start=t_harvested,
-                                     end=t_done)
+                with obs.stage("topk.await", batch=seq, n=n) as aw:
+                    res = jax.tree_util.tree_map(np.asarray, res)  # blocks
+                with obs.stage("topk.account", batch=seq, n=n):
+                    dt = aw.end - enq.start
+                    if res.upper is None:
+                        res = res._replace(upper=np.full(
+                            (np.asarray(res.values).shape[0],), -np.inf,
+                            np.float32))
+                    req_stats = self.stats.setdefault(
+                        get_engine(method).name, ServeStats())
+                    if bud is not None:
+                        self.server._note_certificates(
+                            req_stats, run_eng.name, bud, res)
+                    key = (run_eng.name if bud is None
+                           else f"{run_eng.name}@budget")
+                    per_q = dt / max(n, 1)
+                    prev = self.server._cost_ewma.get(key)
+                    self.server._cost_ewma[key] = (
+                        per_q if prev is None else 0.8 * prev + 0.2 * per_q)
+                    self.cost_table.observe(key, batch_bucket(n), label,
+                                            per_q)
+                    self.server._record(run_eng.name, res, dt, n,
+                                        info.delta_scored, sign_label=label)
+                    if tinfo is not None:
+                        self._stamp_spans(misses, tinfo, enq, aw, seq)
                 # only the EXACT path populates the cache (bud is the
                 # effective budget: a ladder downgrade never caches)
                 self._fulfill(misses, method, res,
-                              cache_token=None if bud is not None
-                              else token)
+                              None if bud is not None else token, seq)
             except BaseException as exc:       # noqa: BLE001 — relayed
                 for r in misses:
                     r.fail(exc)
@@ -667,24 +646,52 @@ class AsyncTopKServer:
                     self._inflight_batches -= 1
                     self._cond.notify_all()
 
+    @staticmethod
+    def _stamp_spans(misses: List[_Request], tinfo: dict, enq: obs.stage,
+                     aw: obs.stage, seq: int) -> None:
+        """The stage spans of one harvested micro-batch, onto each of its
+        traced riders."""
+        t_done = time.perf_counter()
+        co, rt = tinfo["coalesce"], tinfo["route"]
+        for r in misses:
+            if r.trace is None:
+                continue
+            r.trace.root.set(engine=tinfo["engine"], version=tinfo["version"],
+                             epoch=tinfo["epoch"], batch=seq)
+            r.trace.span("queue_wait", start=r.t_enqueue, end=co[0])
+            r.trace.span("coalesce", start=co[0], end=co[1],
+                         batch_size=tinfo["batch_size"])
+            r.trace.span("route", start=rt[0], end=rt[1],
+                         engine=tinfo["engine"], rung=tinfo["rung"],
+                         cost_entry=tinfo["cost_entry"],
+                         predicted_us=tinfo["predicted_us"])
+            r.trace.span("dispatch", start=rt[1], end=enq.start)
+            r.trace.span("enqueue", start=enq.start, end=enq.end,
+                         engine=tinfo["engine"], sign=tinfo["sign"],
+                         version=tinfo["version"], epoch=tinfo["epoch"])
+            r.trace.span("await", start=enq.end, end=aw.end)
+            r.trace.span("harvest", start=aw.end, end=t_done)
+
     def _fulfill(self, batch: List[_Request], method: str,
-                 res: TopKResult, cache_token: Optional[tuple]) -> None:
+                 res: TopKResult, cache_token: Optional[tuple],
+                 seq: int) -> None:
         """Unpad a batched result into per-request rows, fulfil the
         futures, and (exact results only) populate the cache."""
-        vals = np.asarray(res.values)
-        ids = np.asarray(res.indices)
-        nsc = np.asarray(res.n_scored)
-        depth = np.asarray(res.depth)
-        upper = (np.full((vals.shape[0],), -np.inf, np.float32)
-                 if res.upper is None else np.asarray(res.upper))
-        t_merge = time.perf_counter()
-        for i, r in enumerate(batch):
-            row = (vals[i], ids[i], nsc[i], depth[i], upper[i])
-            if cache_token is not None:
-                self.cache.insert((r.u.tobytes(), r.k, cache_token), row)
-            if r.trace is not None:
-                r.trace.span("merge", start=t_merge)
-            self._finish_request(r, method, row)
+        with obs.stage("topk.fulfil", batch=seq, n=len(batch)) as st:
+            vals = np.asarray(res.values)
+            ids = np.asarray(res.indices)
+            nsc = np.asarray(res.n_scored)
+            depth = np.asarray(res.depth)
+            upper = (np.full((vals.shape[0],), -np.inf, np.float32)
+                     if res.upper is None else np.asarray(res.upper))
+            for i, r in enumerate(batch):
+                row = (vals[i], ids[i], nsc[i], depth[i], upper[i])
+                if cache_token is not None:
+                    self.cache.insert((r.u.tobytes(), r.k, cache_token),
+                                      row)
+                if r.trace is not None:
+                    r.trace.span("merge", start=st.start)
+                self._finish_request(r, method, row)
 
     def _finish_request(self, r: _Request, method: str,
                         row: tuple) -> None:

@@ -35,6 +35,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import itertools
 import threading
 import time
 from typing import Callable, Dict, List, Optional
@@ -306,6 +307,9 @@ class TopKServer:
         self._cost_ewma: Dict[str, float] = {}
         self._admit_lock = threading.Lock()
         self._inflight = 0
+        #: micro-batch sequence numbers, the ``batch`` argument of the
+        #: ``obs.stage`` events (shared with an ``AsyncTopKServer`` front)
+        self._batch_seq = itertools.count(1)
 
     @property
     def ctx(self) -> EngineContext:
@@ -564,34 +568,38 @@ class TopKServer:
         the finiteness scan — reading them back would break the
         no-round-trip contract above).
         """
-        engine: Engine = get_engine(method)
-        if int(k) <= 0:
-            raise ValueError(f"k must be a positive int, got {k!r}")
-        if budget is not None and int(budget) <= 0:
-            raise ValueError(
-                f"budget must be a positive int or None, got {budget!r}")
-        if deadline_ms is not None and float(deadline_ms) < 0:
-            raise ValueError(
-                f"deadline_ms must be >= 0 or None, got {deadline_ms!r}")
-        # Keep the batch wherever the caller had it: host inputs are
-        # sliced and dispatched as numpy (auto's nnz statistic never
-        # touches the device), device-resident inputs stay on device with
-        # no round-trip (select_engine reads them back once per chunk
-        # only when method="auto").
-        if isinstance(U, jax.Array):
-            U_all = jnp.atleast_2d(U)
-        else:
-            U_all = np.atleast_2d(np.asarray(U, np.float32))
-        if U_all.ndim != 2:
-            raise ValueError(
-                f"U must be [B, R] or [R], got shape {U_all.shape}")
-        rank = self.catalogue.rank
-        if U_all.shape[1] != rank:
-            raise ValueError(
-                f"query rank {U_all.shape[1]} != catalogue rank {rank}")
-        if isinstance(U_all, np.ndarray) and not np.all(np.isfinite(U_all)):
-            bad = int(np.argwhere(~np.isfinite(U_all).all(axis=1))[0, 0])
-            raise ValueError(f"query row {bad} contains NaN/Inf values")
+        seq = next(self._batch_seq)
+        with obs.stage("topk.validate", batch=seq) as st:
+            engine: Engine = get_engine(method)
+            if int(k) <= 0:
+                raise ValueError(f"k must be a positive int, got {k!r}")
+            if budget is not None and int(budget) <= 0:
+                raise ValueError(f"budget must be a positive int or None, "
+                                 f"got {budget!r}")
+            if deadline_ms is not None and float(deadline_ms) < 0:
+                raise ValueError(f"deadline_ms must be >= 0 or None, got "
+                                 f"{deadline_ms!r}")
+            # Keep the batch wherever the caller had it: host inputs are
+            # sliced and dispatched as numpy (auto's nnz statistic never
+            # touches the device), device-resident inputs stay on device
+            # with no round-trip (select_engine reads them back once per
+            # chunk only when method="auto").
+            if isinstance(U, jax.Array):
+                U_all = jnp.atleast_2d(U)
+            else:
+                U_all = np.atleast_2d(np.asarray(U, np.float32))
+            st.set(n=int(U_all.shape[0]))
+            if U_all.ndim != 2:
+                raise ValueError(
+                    f"U must be [B, R] or [R], got shape {U_all.shape}")
+            rank = self.catalogue.rank
+            if U_all.shape[1] != rank:
+                raise ValueError(f"query rank {U_all.shape[1]} != "
+                                 f"catalogue rank {rank}")
+            if isinstance(U_all, np.ndarray) \
+                    and not np.all(np.isfinite(U_all)):
+                bad = int(np.argwhere(~np.isfinite(U_all).all(axis=1))[0, 0])
+                raise ValueError(f"query row {bad} contains NaN/Inf values")
         if deadline_ms is None:
             deadline_ms = self.policy.deadline_ms
         t_admit = time.perf_counter()
@@ -600,8 +608,8 @@ class TopKServer:
         for i in range(0, U_all.shape[0], self.max_batch):
             chunk = U_all[i: i + self.max_batch]
             n = chunk.shape[0]
-            eng = (select_engine(self.ctx, chunk)
-                   if engine.name == "auto" else engine)
+            # the first chunk carries the call's sequence number
+            cseq = seq if i == 0 else next(self._batch_seq)
             # admission: overload first (cheap counter check), then the
             # deadline ladder on the time this query has left
             with self._admit_lock:
@@ -609,13 +617,25 @@ class TopKServer:
                               and self.policy.shed_on_overload)
                 self._inflight += 1
             try:
-                if overloaded:
-                    run_eng, bud, rung = None, None, "shed"
-                else:
-                    remaining = None if deadline_ms is None else (
-                        deadline_ms / 1e3
-                        - (time.perf_counter() - t_admit))
-                    run_eng, bud, rung = self._admit(eng, n, remaining)
+                with obs.stage("topk.route", batch=cseq, n=n):
+                    eng = (select_engine(self.ctx, chunk)
+                           if engine.name == "auto" else engine)
+                    if overloaded:
+                        run_eng, bud, rung = None, None, "shed"
+                    else:
+                        remaining = None if deadline_ms is None else (
+                            deadline_ms / 1e3
+                            - (time.perf_counter() - t_admit))
+                        run_eng, bud, rung = self._admit(eng, n, remaining)
+                    # sign bucket of this chunk, for the per-bucket serve
+                    # stats — only engines with batch specialisation pay
+                    # the (host-side, input-value-only) read; it mirrors
+                    # the bucket the dispatch itself computes for the
+                    # compile key (DESIGN.md §11)
+                    label = (sign_bucket_label(
+                                run_eng.batch_config(self.ctx, chunk))
+                             if run_eng is not None
+                             and run_eng.batch_config is not None else "")
                 if rung != "full":
                     req_stats.bump_degradation(rung)
                     obs.on_degradation(engine.name, rung)
@@ -627,48 +647,46 @@ class TopKServer:
                     continue
                 if bud is None:
                     bud = budget  # explicit caller budget, not a downgrade
-                # sign bucket of this chunk, for the per-bucket serve
-                # stats — only engines with batch specialisation pay the
-                # (host-side, input-value-only) read; it mirrors the
-                # bucket the dispatch itself computes for the compile key
-                # (DESIGN.md §11)
-                label = (sign_bucket_label(
-                            run_eng.batch_config(self.ctx, chunk))
-                         if run_eng.batch_config is not None else "")
-                t0 = time.perf_counter()
-                res, info = self.catalogue.query(run_eng, chunk, k,
-                                                 budget=bud)
-                res = jax.tree_util.tree_map(np.asarray, res)
-                dt = time.perf_counter() - t0
+                with obs.stage("topk.enqueue", batch=cseq, n=n) as enq:
+                    res, info = self.catalogue.query(run_eng, chunk, k,
+                                                     budget=bud)
+                with obs.stage("topk.await", batch=cseq, n=n) as aw:
+                    res = jax.tree_util.tree_map(np.asarray, res)
+                dt = aw.end - enq.start
             finally:
                 with self._admit_lock:
                     self._inflight -= 1
-            if res.upper is None:
-                # legacy/sharded paths carry no bound; they are exact, so
-                # the vacuous bound (everything certified) is the truth —
-                # and it keeps chunk results concatenable
-                res = res._replace(upper=np.full(
-                    (np.asarray(res.values).shape[0],), -np.inf,
-                    np.float32))
-            if bud is not None:
-                self._note_certificates(req_stats, run_eng.name, bud, res)
-            # cost model: learn per-query seconds per (engine, budgeted?)
-            key = run_eng.name if bud is None else f"{run_eng.name}@budget"
-            prev = self._cost_ewma.get(key)
-            per_q = dt / max(n, 1)
-            self._cost_ewma[key] = (per_q if prev is None
-                                    else 0.8 * prev + 0.2 * per_q)
-            # ... and granularly per (engine, batch-bucket, sign) in the
-            # shared table the serving router reads (DESIGN.md §13)
-            self.cost_table.observe(key, batch_bucket(n), label, per_q)
-            self._record(run_eng.name, res, dt, n,
-                         info.delta_scored, sign_label=label)
+            with obs.stage("topk.account", batch=cseq, n=n):
+                if res.upper is None:
+                    # legacy/sharded paths carry no bound; they are exact,
+                    # so the vacuous bound (everything certified) is the
+                    # truth — and it keeps chunk results concatenable
+                    res = res._replace(upper=np.full(
+                        (np.asarray(res.values).shape[0],), -np.inf,
+                        np.float32))
+                if bud is not None:
+                    self._note_certificates(req_stats, run_eng.name, bud,
+                                            res)
+                # cost model: learn per-query seconds per (engine,
+                # budgeted?) ...
+                key = (run_eng.name if bud is None
+                       else f"{run_eng.name}@budget")
+                prev = self._cost_ewma.get(key)
+                per_q = dt / max(n, 1)
+                self._cost_ewma[key] = (per_q if prev is None
+                                        else 0.8 * prev + 0.2 * per_q)
+                # ... and granularly per (engine, batch-bucket, sign) in
+                # the shared table the serving router reads (DESIGN.md §13)
+                self.cost_table.observe(key, batch_bucket(n), label, per_q)
+                self._record(run_eng.name, res, dt, n,
+                             info.delta_scored, sign_label=label)
             outs.append(res)
         req_us = 1e6 * (time.perf_counter() - t_admit)
         req_stats.record_request_latency(req_us)
         obs.on_request_done(engine.name, req_us)
-        return jax.tree_util.tree_map(
-            lambda *xs: np.concatenate(xs, axis=0), *outs)
+        with obs.stage("topk.fulfil", batch=seq, n=int(U_all.shape[0])):
+            return jax.tree_util.tree_map(
+                lambda *xs: np.concatenate(xs, axis=0), *outs)
 
 
 class TwoStageRanker:

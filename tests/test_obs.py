@@ -2,8 +2,10 @@
 tests, the ServeStats façade contract, thread-safety under concurrent
 recording + compaction, and the end-to-end span↔journal join."""
 
+import gc
 import threading
 
+import jax
 import numpy as np
 import pytest
 
@@ -14,15 +16,21 @@ from repro.serving.pipeline import AsyncTopKServer
 from repro.serving.server import LATENCY_RING, ServeStats, TopKServer
 
 
+#: the process-wide tracer's rate, before any test here changes it
+DEFAULT_SAMPLE_RATE = obs.TRACER.sample_rate
+
+
 @pytest.fixture(autouse=True)
 def _clean_obs():
-    """Every test sees empty default stores and an enabled layer."""
+    """Every test sees empty default stores, an enabled layer and a
+    tracer that keeps every request."""
     obs.reset()
     obs.set_enabled(True)
     obs.TRACER.sample_rate = 1.0
     yield
     obs.reset()
     obs.set_enabled(True)
+    obs.TRACER.sample_rate = DEFAULT_SAMPLE_RATE
 
 
 # ---------------------------------------------------------------------------
@@ -132,13 +140,13 @@ def test_trace_tree_and_store_bound():
     for i in range(3):
         t = tr.start_trace("req", k=i)
         t.span("queue_wait", start=0.0, end=0.5)
-        t.span("device", start=0.5, end=1.0, engine="bta")
+        t.span("await", start=0.5, end=1.0, engine="bta")
         t.finish()
     done = tr.traces()
     assert len(done) == 2          # bounded store evicted the oldest
     tree = done[-1].format_tree()
     assert "queue_wait" in tree and "engine=bta" in tree
-    assert done[-1].find("device").duration_us == pytest.approx(5e5)
+    assert done[-1].find("await").duration_us == pytest.approx(5e5)
 
 
 def test_journal_filter_tail_and_capacity():
@@ -322,7 +330,7 @@ def test_fault_firing_emits_event():
 
 def test_async_request_span_joins_compaction_event():
     """The acceptance trace: one async request's span tree names the
-    engine, the cost-table entry, queue/coalesce/device stage
+    engine, the cost-table entry, queue/coalesce/enqueue/await stage
     durations, and the (version, epoch) it ran against — and that
     version joins to the compaction.success journal event that
     produced the snapshot."""
@@ -345,19 +353,21 @@ def test_async_request_span_joins_compaction_event():
         # the stage ladder, in order, every span closed
         names = [s.name for s in t.spans]
         for stage in ("queue_wait", "coalesce", "route", "dispatch",
-                      "device", "harvest", "merge"):
+                      "enqueue", "await", "harvest", "merge"):
             assert stage in names, stage
         assert all(s.t_end is not None for s in t.spans)
-        dev = t.find("device")
-        assert dev.attrs["engine"] == "bta"
+        # enqueue and await tile the executor call to the host result
+        assert t.find("enqueue").t_end == t.find("await").t_start
+        enq = t.find("enqueue")
+        assert enq.attrs["engine"] == "bta"
         assert "bta" in t.find("route").attrs["cost_entry"]
         assert t.find("queue_wait").duration_us >= 0.0
         # the JOIN: the span ran against the snapshot the journal's
         # compaction.success event says it produced
-        assert dev.attrs["version"] == version
+        assert enq.attrs["version"] == version
         assert t.root.attrs["version"] == version
         joined = obs.JOURNAL.events("compaction.success",
-                                    version=dev.attrs["version"])
+                                    version=enq.attrs["version"])
         assert len(joined) == 1
         # the registry saw the same request on its always-on counters
         assert obs.QUERIES.value(engine="bta") >= 1
@@ -370,7 +380,7 @@ def test_async_request_span_joins_fold_event():
     as compaction.success, and a traced request that ran against the
     folded catalogue joins to it. A fold moves rows without changing
     visible contents, so it must NOT bump the epoch — the request's
-    device span carries the very same (version, epoch) the fold event
+    enqueue span carries the very same (version, epoch) the fold event
     recorded."""
     from repro.core import ShardedLsmCatalogue
 
@@ -395,12 +405,158 @@ def test_async_request_span_joins_fold_event():
         h = srv.submit(rng.standard_normal(7).astype(np.float32), 4)
         h.result(timeout=30)
         t = obs.TRACER.traces()[-1]
-        dev = t.find("device")
+        enq = t.find("enqueue")
         # the JOIN, both keys: the request ran against exactly the
         # (version, epoch) the fold event was journalled under
-        assert dev.attrs["version"] == ev["version"]
-        assert dev.attrs["epoch"] == ev["epoch"]
+        assert enq.attrs["version"] == ev["version"]
+        assert enq.attrs["epoch"] == ev["epoch"]
         joined = obs.JOURNAL.events("compaction.fold_l1",
-                                    version=dev.attrs["version"],
-                                    epoch=dev.attrs["epoch"])
+                                    version=enq.attrs["version"],
+                                    epoch=enq.attrs["epoch"])
         assert joined and joined[-1].fields == ev
+
+
+# ---------------------------------------------------------------------------
+# host stages, GC pauses and compiles on the profiler's clock
+# ---------------------------------------------------------------------------
+
+ASYNC_STAGES = ["topk.coalesce", "topk.route", "topk.enqueue",
+                "topk.backpressure", "topk.await", "topk.account",
+                "topk.fulfil"]
+SYNC_STAGES = ["topk.validate", "topk.route", "topk.enqueue", "topk.await",
+               "topk.account", "topk.fulfil"]
+
+
+def _profiled(tmp_path, fn):
+    """Run ``fn`` under a ``jax.profiler`` trace; return its host events
+    named ``topk.*`` or ``py.gc`` as ``(name, start_ns, args)``, in
+    start order."""
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(tmp_path.glob("**/*.xplane.pb"))[-1]
+    pd = ProfileData.from_file(str(path))
+    events = [(e.name, e.start_ns, dict(e.stats))
+              for p in pd.planes if p.name.startswith("/host:")
+              for line in p.lines for e in line.events
+              if e.name.startswith("topk.") or e.name == "py.gc"]
+    return sorted(events, key=lambda e: e[1])
+
+
+def _by_batch(events):
+    out = {}
+    for name, _, args in events:
+        if name.startswith("topk."):
+            out.setdefault(args["batch"], []).append((name, args["n"]))
+    return out
+
+
+def test_async_pipeline_stages_land_in_the_profile(tmp_path):
+    """Every stage of the async path is one profiler event per
+    micro-batch, in order, carrying the batch's sequence number and real
+    size; a sampled request's root span carries the same number; a
+    collection inside the trace is a ``py.gc`` event."""
+    rng = np.random.default_rng(4)
+    T = rng.standard_normal((227, 7)).astype(np.float32)
+    Q = rng.standard_normal((5, 7)).astype(np.float32)
+    with AsyncTopKServer(SepLRModel(T), max_batch=8,
+                         method="naive") as srv:
+        srv.warmup(4, engines=["naive"])
+
+        def serve():
+            for q in Q:                  # one at a time: a batch each
+                srv.submit(q, 4).result(timeout=30)
+            gc.collect()
+
+        events = _profiled(tmp_path, serve)
+    batches = _by_batch(events)
+    assert len(batches) == len(Q)
+    for seq, stages in batches.items():
+        assert stages == [(name, 1) for name in ASYNC_STAGES], seq
+    roots = {t.root.attrs["batch"] for t in obs.TRACER.traces()}
+    assert roots == set(batches)
+    gcs = [args for name, _, args in events if name == "py.gc"]
+    assert {"generation": 2} in gcs
+
+
+def test_sync_query_stages_land_in_the_profile(tmp_path):
+    """``TopKServer.query``: validate and fulfil once per call, route to
+    account once per chunk; the first chunk shares the call's number."""
+    rng = np.random.default_rng(5)
+    srv = TopKServer(SepLRModel(
+        rng.standard_normal((300, 6)).astype(np.float32)), max_batch=8)
+    srv.warmup(3, batch_sizes=(4, 8), engines=["naive"])
+    U1 = rng.standard_normal((4, 6)).astype(np.float32)
+    U2 = rng.standard_normal((12, 6)).astype(np.float32)
+
+    def serve():
+        srv.query(U1, 3, method="naive")
+        srv.query(U2, 3, method="naive")
+
+    batches = _by_batch(_profiled(tmp_path, serve))
+    (a, b, c) = sorted(batches)
+    assert batches[a] == [(name, 4) for name in SYNC_STAGES]
+    assert batches[b] == [("topk.validate", 12), ("topk.route", 8),
+                          ("topk.enqueue", 8), ("topk.await", 8),
+                          ("topk.account", 8), ("topk.fulfil", 12)]
+    assert batches[c] == [(name, 4) for name in SYNC_STAGES[1:-1]]
+
+
+def test_a_disabled_layer_opens_no_stage_and_keeps_no_hook(tmp_path):
+    rng = np.random.default_rng(6)
+    srv = TopKServer(SepLRModel(
+        rng.standard_normal((100, 5)).astype(np.float32)), max_batch=4)
+    U = rng.standard_normal((2, 5)).astype(np.float32)
+    srv.query(U, 2, method="naive")
+    obs.set_enabled(False)
+    assert obs._on_gc not in gc.callbacks
+    compiles = obs.XLA_COMPILES.total()
+    journalled = len(obs.JOURNAL.events("xla.compile"))
+
+    def serve():
+        srv.query(U, 2, method="naive")
+        jax.jit(lambda x: x + 3)(np.ones(3))   # a fresh compile
+        gc.collect()
+
+    assert _profiled(tmp_path, serve) == []
+    assert obs.XLA_COMPILES.total() == compiles
+    assert len(obs.JOURNAL.events("xla.compile")) == journalled
+    obs.set_enabled(True)
+    assert gc.callbacks.count(obs._on_gc) == 1
+
+
+def test_default_tracer_keeps_one_request_in_a_hundred():
+    assert DEFAULT_SAMPLE_RATE == 0.01
+    assert obs.Tracer().sample_rate == 1.0
+    tr = obs.Tracer(capacity=64, sample_rate=DEFAULT_SAMPLE_RATE)
+    kept = [tr.start_trace("t") is not None for _ in range(1000)]
+    assert sum(kept) == 10 and kept.index(True) == 99
+
+
+def test_compile_listener_names_the_function():
+    def add_seven(x):
+        return x + 7
+
+    jax.jit(add_seven)(np.ones(5, np.float32))
+    assert obs.XLA_COMPILES.value(fun="jit(add_seven)") == 1
+    ev = obs.JOURNAL.events("xla.compile", fun="jit(add_seven)")
+    assert len(ev) == 1 and ev[0].fields["seconds"] > 0
+
+
+def test_gc_pause_is_recorded_without_waiting_on_a_held_lock():
+    """A collection that interrupts a holder of the pause histogram's
+    lock on the same thread must not wait on it: the pause is kept and
+    recorded at the next collection."""
+    before = obs.GC_PAUSE.count(generation="2")
+    with obs.GC_PAUSE._lock:
+        gc.collect()
+    assert obs.GC_PAUSE.count(generation="2") == before
+    gc.collect()
+    assert obs.GC_PAUSE.count(generation="2") >= before + 2
+    assert obs.GC_PAUSE.sum(generation="2") > 0
