@@ -2,7 +2,7 @@
 
 Public API:
   SepLRModel, build_index, TopKIndex
-  naive_topk                      — baseline (matmul + top_k)
+  naive_topk                      — baseline (matmul + exact top-K)
   threshold_topk / *_np           — the Threshold Algorithm (Alg. 2)
   fagin_topk_np                   — Fagin's Algorithm (Alg. 1)
   partial_threshold_topk_np       — Partial TA (Alg. 3)

@@ -61,7 +61,7 @@ Registered engines:
 ================  =====  ===========  ========  ===========  ==================================
 name              exact  needs_index  backend   layout       algorithm
 ================  =====  ===========  ========  ===========  ==================================
-``naive``         yes    no           jax       row_major    full matmul + top_k
+``naive``         yes    no           jax       row_major    full matmul + exact top-k
 ``ta``            yes    yes          jax       list_major   chunked TA rounds (count-faithful)
 ``bta``           yes    yes          jax       list_major   Block Threshold Algorithm
 ``norm``          yes    yes          jax       norm_major   Cauchy-Schwarz norm-block scan
@@ -119,7 +119,8 @@ from repro.core.layout import (DEFAULT_PREFIX_DEPTH,
                                LIST_LAYOUT_MIN_TARGETS,
                                build_layout, pad_rank_by_item,
                                pad_zero_rows)
-from repro.core.naive import SCORE_PRECISION, TopKResult
+from repro.core.naive import (SCORE_PRECISION, TopKResult, select_path,
+                              select_topk)
 from repro.core.strategies import sign_bucket, sign_bucket_label
 
 Array = jnp.ndarray
@@ -603,6 +604,8 @@ class EngineContext:
         fn = _ARG_EXECUTORS[engine.name]
         before = _TRACE_TOTALS.get(engine.name, 0)
         bud = None if budget is None else int(budget)
+        if engine.select_path is not None:
+            obs.on_topk_select(engine.name, engine.select_path(args, int(k)))
         res = fn(args, U, k=int(k), cfg=(acfg, bcfg, bud))
         delta = _TRACE_TOTALS.get(engine.name, 0) - before
         if delta:
@@ -816,7 +819,12 @@ class Engine:
     engine's memory traffic for a measured :class:`TopKResult` (per-query
     means: rows gathered, contiguous rows read, bytes moved) — the
     benchmark sweep records it so layout wins show up in the perf
-    trajectory, not just wall-clock.
+    trajectory, not just wall-clock. ``select_path(args, k)`` is how an
+    engine whose executor picks its top-K selection from the argument
+    shapes names the path it takes (``"two_stage"`` or ``"direct"``,
+    DESIGN.md §4): every dispatch of such an engine counts it, on the
+    host, in ``repro_topk_select_total{engine, path}``. ``naive`` sets
+    it; an engine that selects one way only leaves it ``None``.
     """
 
     name: str
@@ -843,6 +851,7 @@ class Engine:
     supports_budget: bool = False
     backend: str = "jax"
     layout: Optional[str] = None
+    select_path: Optional[Callable[[Any, int], str]] = None
     host_only: bool = False
     traffic: Optional[
         Callable[["EngineContext", TopKResult], Dict[str, float]]] = None
@@ -923,7 +932,15 @@ def _naive_args(ctx: EngineContext, bucket: int):
             "m_real": ctx.m_real}
 
 
+def _naive_select_path(args, k):
+    mb = args["targets"].shape[0]
+    return select_path(mb, min(k, mb))
+
+
 def _naive_run(args, U, k, cfg):
+    """Score every row, mask the pad rows, select exactly: the two-stage
+    selection where the shape engages it (DESIGN.md §4, "Exact two-stage
+    selection")."""
     T, m = args["targets"], args["m_real"]
     mb = T.shape[0]
     scores = jnp.matmul(U, T.T, precision=SCORE_PRECISION)
@@ -931,7 +948,7 @@ def _naive_run(args, U, k, cfg):
     # a real (possibly all-negative) score
     scores = jnp.where(jnp.arange(mb, dtype=jnp.int32)[None, :] < m,
                        scores, NEG_INF)
-    vals, ids = jax.lax.top_k(scores, min(k, mb))
+    vals, ids = select_topk(scores, min(k, mb))
     ids = jnp.where(jnp.isneginf(vals), -1, ids)
     b = U.shape[0]
     # a full scan leaves nothing unenumerated: the bound on unseen items
@@ -1378,8 +1395,9 @@ register_engine(Engine(
     exact=True, needs_index=False,
     supports_batch=True, supports_budget=True,  # budget ignored: one matmul
     backend="jax", layout="row_major",
-    traffic=_naive_traffic,
-    description="full matmul + lax.top_k (strongest wall-clock baseline)"))
+    traffic=_naive_traffic, select_path=_naive_select_path,
+    description="full matmul + exact two-stage top-k (strongest "
+                "wall-clock baseline)"))
 register_engine(Engine(
     name="ta", make_args=_list_args, run_args=_ta_run, arg_config=_ta_cfg,
     batch_config=_list_batch_cfg,

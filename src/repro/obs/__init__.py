@@ -142,6 +142,11 @@ QUEUE_WAIT = REGISTRY.histogram(
 BATCH_SIZE = REGISTRY.histogram(
     "repro_batch_size", "Coalesced micro-batch sizes (exact, pre-pad).",
     labels=(), buckets=SIZE_BUCKETS)
+TOPK_SELECT = REGISTRY.counter(
+    "repro_topk_select_total",
+    "Dispatched batches per engine and top-K selection path "
+    "(two_stage or direct, DESIGN.md §4).",
+    labels=("engine", "path"))
 SIGN_BATCHES = REGISTRY.counter(
     "repro_sign_batches_total",
     "Batches served per sign bucket (the DESIGN.md §11 compile axis).",
@@ -230,6 +235,13 @@ def on_batch_served(engine: str, n: int, n_scored: int, depth_sum: int,
     BATCH_LATENCY.observe(per_query_us, engine=engine)
     if sign_label:
         SIGN_BATCHES.inc(engine=engine, sign=sign_label)
+
+
+def on_topk_select(engine: str, path: str) -> None:
+    """One batch dispatched to an executor whose top-K takes ``path``."""
+    if not REGISTRY.enabled:
+        return
+    TOPK_SELECT.inc(engine=engine, path=path)
 
 
 def on_request_done(engine: str, us: float) -> None:

@@ -5,6 +5,7 @@ and are skipped automatically when hypothesis is not installed; everything
 here runs with numpy-seeded determinism only.
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -20,6 +21,7 @@ from repro.core import (
     threshold_topk_np,
 )
 from repro.core.index import build_index
+from repro.core.naive import select_path, select_topk
 from repro.core.toy import TOY_BEST_ITEM, TOY_SCORES, TOY_T, TOY_U, table2_adversarial
 
 
@@ -219,3 +221,54 @@ def test_halted_norm_pruned_budget_respected():
     ids = ids[ids >= 0]
     np.testing.assert_allclose(np.asarray(r.values)[: len(ids)], scores[ids],
                                atol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# the naive executor's selection: bit-identical to lax.top_k
+# ---------------------------------------------------------------------------
+
+def _scores(kind, rng, shape):
+    if kind == "ties":
+        return rng.integers(-2, 3, shape).astype(np.float32)
+    if kind == "neg_inf":
+        x = rng.standard_normal(shape).astype(np.float32)
+        x[rng.random(shape) < 0.5] = -np.inf
+        return x
+    if kind == "neg_inf_runs":      # whole chunks at -inf, few finite lanes
+        x = np.full(shape, -np.inf, np.float32)
+        live = rng.random(shape) < 0.002
+        x[live] = rng.integers(-1, 2, shape)[live]
+        return x
+    if kind == "all_negative":
+        return -np.abs(rng.standard_normal(shape)).astype(np.float32) - 1.0
+    if kind == "signed_zeros":
+        return rng.choice(np.array([-0.0, 0.0, -1.0], np.float32), shape)
+    return rng.standard_normal(shape).astype(np.float32)
+
+
+# 2 * k * 128 <= m engages the two-stage path: m = 8192 engages up to k = 32
+@pytest.mark.parametrize("kind,batch,m,k,path", [
+    ("ties", 3, 8192, 32, "two_stage"),
+    ("ties", 1, 8192, 33, "direct"),
+    ("neg_inf", 2, 4096, 4, "two_stage"),
+    ("neg_inf_runs", 1, 16384, 16, "two_stage"),
+    ("all_negative", 1, 8192, 32, "two_stage"),
+    ("all_negative", 4, 8192, 33, "direct"),
+    ("signed_zeros", 2, 8192, 5, "two_stage"),
+    ("normal", None, 8192, 8, "two_stage"),
+    ("normal", 2, 8200, 1, "direct"),
+])
+def test_select_topk_is_lax_top_k(kind, batch, m, k, path):
+    """Same float bits and the same ids as ``lax.top_k`` (ties to the
+    lower id), on both sides of the engagement rule."""
+    assert select_path(m, k) == path
+    shape = (m,) if batch is None else (batch, m)
+    sel = jax.jit(select_topk, static_argnums=1)
+    ref = jax.jit(jax.lax.top_k, static_argnums=1)
+    for seed in range(4):
+        x = jnp.asarray(_scores(kind, np.random.default_rng(seed), shape))
+        v, i = sel(x, k)
+        rv, ri = ref(x, k)
+        np.testing.assert_array_equal(np.asarray(v).view(np.int32),
+                                      np.asarray(rv).view(np.int32))
+        np.testing.assert_array_equal(np.asarray(i), np.asarray(ri))

@@ -121,6 +121,41 @@ def test_engine_ids_are_valid_catalogue_ids():
                                        err_msg=eng.name)
 
 
+@pytest.mark.parametrize("sign", ["mixed", "negative"])
+@pytest.mark.parametrize("m_real,bucket", [
+    (2 ** 13 - 1, None), (2 ** 13, None), (2 ** 13 + 1, None), (3, 2 ** 13)])
+def test_naive_two_stage_selection_is_exact(m_real, bucket, sign):
+    """The naive engine at shapes that engage the two-stage selection
+    (k = 5 over an M-bucket of 8,192 or 16,384): small-integer factors
+    make every score exact in float32 and tie heavily, so the ids must
+    be the float64 reference's (ties to the lower id), pad rows never
+    surface, and ``-1`` marks exactly the ``-inf`` slots."""
+    from repro.core.naive import select_path
+
+    rng = np.random.default_rng(m_real)
+    T = rng.integers(-2, 3, (m_real, 6)).astype(np.float32)
+    U = rng.integers(-2, 3, (3, 6)).astype(np.float32)
+    if sign == "negative":      # every real score below zero
+        T, U = np.abs(T) + 1.0, -np.abs(U) - 1.0
+    k = 5
+    ctx = EngineContext(T)
+    eng = get_engine("naive")
+    args = ctx.engine_args(eng, bucket=bucket, cache=False)
+    assert select_path(args["targets"].shape[0], k) == "two_stage"
+    res = ctx._dispatch_args(eng, args, jnp.asarray(U), k)
+    vals, ids = np.asarray(res.values), np.asarray(res.indices)
+
+    scores = U.astype(np.float64) @ T.T.astype(np.float64)
+    want = np.argsort(-scores, axis=1, kind="stable")[:, :k]
+    n = min(k, m_real)
+    np.testing.assert_array_equal(ids[:, :n], want[:, :n])
+    np.testing.assert_array_equal(
+        vals[:, :n], np.take_along_axis(scores, want[:, :n], 1))
+    assert np.all(ids < m_real)
+    np.testing.assert_array_equal(ids == -1, np.isneginf(vals))
+    assert np.all(np.isneginf(vals[:, n:]))
+
+
 # ---------------------------------------------------------------------------
 # auto policy
 # ---------------------------------------------------------------------------

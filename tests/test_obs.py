@@ -123,6 +123,27 @@ def test_disable_switch_stops_recording():
     assert obs.QUERIES.total() == 4
 
 
+def test_topk_select_counts_each_dispatched_batch_by_path():
+    """``repro_topk_select_total{engine, path}``: one per batch the
+    server dispatches, on the path the shape rule names (8,192 rows:
+    two-stage up to k = 32, direct past it); nothing while disabled."""
+    rng = np.random.default_rng(2)
+    T = rng.standard_normal((8192, 4)).astype(np.float32)
+    srv = TopKServer(SepLRModel(T), max_batch=4)
+    U = rng.standard_normal((6, 4)).astype(np.float32)
+    srv.query(U, k=5, method="naive")          # chunks of 4 and 2
+    srv.query(U[:3], k=33, method="naive")
+    assert obs.TOPK_SELECT.value(engine="naive", path="two_stage") == 2
+    assert obs.TOPK_SELECT.value(engine="naive", path="direct") == 1
+    srv.query(U[:2], k=5, method="bta")        # no selection path of its own
+    assert obs.TOPK_SELECT.total() == 3
+
+    obs.set_enabled(False)
+    srv.query(U, k=5, method="naive")
+    srv.query(U[:3], k=33, method="naive")
+    assert obs.TOPK_SELECT.total() == 3
+
+
 # ---------------------------------------------------------------------------
 # trace spans + event journal
 # ---------------------------------------------------------------------------

@@ -15,6 +15,7 @@ test workers all import every test file.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -100,6 +101,48 @@ def test_engine_executor_compiles_for_one_v5e(engine, batch, one_chip,
     compiled = _ARG_EXECUTORS[engine].lower(
         args, U, k=K, cfg=(acfg, bcfg, None)).compile()
     _check_memory(compiled, M_BUCKET * R * 4)
+
+
+def _selection_sizes(hlo: str) -> list:
+    """Element count of the operand of every ``TopK`` custom call and
+    every ``sort`` in a compiled module's text: XLA lowers ``top_k`` to
+    either, and may itself split one row into many (at B = 1 a direct
+    ``top_k`` over 2**20 lanes becomes a sort of ``[128, 8192]``)."""
+    shapes = dict(re.findall(r"%([\w.\-]+) = \w+\[([\d,]*)\]", hlo))
+    sizes = []
+    for line in hlo.splitlines():
+        op = re.search(r"= .*?\b(?:custom-call|sort)\(%([\w.\-]+)", line)
+        if op and ('custom_call_target="TopK"' in line or " sort(" in line):
+            sizes.append(int(np.prod([int(d) for d in
+                                      shapes[op.group(1)].split(",")])))
+    return sizes
+
+
+@pytest.mark.parametrize("batch", [1, 64])
+def test_naive_selection_never_spans_the_catalogue(batch, one_chip,
+                                                   tpu_backend):
+    """At the retrieval cell's width (2**20 rows x 256, k = 200) the
+    naive executor selects in two stages: no ``TopK`` or ``sort`` takes
+    as many scores as the whole catalogue per query, and the program
+    fits one chip. Batched, the candidates' ``TopK`` stays a custom call
+    of the entry computation, so a profile names it (DESIGN.md §4); at
+    B = 1 the compiler lowers it to sorts."""
+    r, k = 256, 200
+    args = {"targets": jax.ShapeDtypeStruct((M_BUCKET, r), jnp.float32,
+                                            sharding=one_chip),
+            "m_real": jax.ShapeDtypeStruct((), jnp.int32,
+                                           sharding=one_chip)}
+    U = jax.ShapeDtypeStruct((batch, r), jnp.float32, sharding=one_chip)
+    compiled = _ARG_EXECUTORS["naive"].lower(
+        args, U, k=k, cfg=((), (), None)).compile()
+    hlo = compiled.as_text()
+    sizes = _selection_sizes(hlo)
+    assert sizes and max(sizes) < batch * M_BUCKET, sizes
+    entry = hlo[hlo.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}")]
+    bare = entry.count('custom_call_target="TopK"')
+    assert bare == (batch > 1), entry
+    _check_memory(compiled, M_BUCKET * r * 4)
 
 
 def test_norm_sharded_compiles_for_four_v5e(topo, tpu_backend):
