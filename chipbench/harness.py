@@ -101,9 +101,10 @@ def engine_traces(srv) -> int:
             + sum(srv.catalogue.trace_counts.values()))
 
 
-def build_server(config: dict, traffic: dict, seed: int):
-    """The catalogue from the seed, handed to the program's server and
-    warmed for this cell alone."""
+def build_server(config: dict, traffic: dict, seed: int, chips: int = 1):
+    """The catalogue from the seed, sharded by rows over the cell's
+    ``chips`` where there are more than one, handed to the program's
+    server and warmed for this cell alone."""
     import jax
 
     from repro.core import SepLRModel
@@ -112,7 +113,7 @@ def build_server(config: dict, traffic: dict, seed: int):
 
     rows = catalogue.rows(seed, catalogue.CATALOGUE, config["rows"],
                           config["rank"], config["catalogue"],
-                          config["block_rows"])
+                          config["block_rows"], chips)
     jax.block_until_ready(rows)
     model = SepLRModel(targets=rows, name=config["name"])
     k, method = int(config["k"]), config["method"]
@@ -286,7 +287,7 @@ def measure(cell: str, seed: int, seconds: float, traced: bool,
     traffic = spec.load_traffic(w["traffic"], base)
     k, method = int(config["k"]), config["method"]
 
-    srv = build_server(config, traffic, seed)
+    srv = build_server(config, traffic, seed, int(w["chips"]))
     rows = gen.query_rows(traffic, config, seed, seconds)
     if traffic["loop"] == "open":
         offsets = gen.arrival_offsets(traffic, seed, seconds)

@@ -89,7 +89,7 @@ def main(argv=None) -> int:
     w = spec.workload(bench, args.workload)
     config = spec.load_config(w["config"])
     traffic = spec.load_traffic(w["traffic"])
-    srv = harness.build_server(config, traffic, args.seed)
+    srv = harness.build_server(config, traffic, args.seed, int(w["chips"]))
     harness.warm_open(srv, config, args.seed)
     results = []
     try:
